@@ -216,8 +216,8 @@ KERNEL_SPACES: dict[str, dict[str, tuple[int, ...]]] = {
     },
     "quantized_cache": {
         # categorical dtype knob: fp16 is the accuracy-fallback arm (keep
-        # the fp pool); fp8 arms appear only where the platform has them
-        "cache_dtype": ("float16", "int8"),
+        # the fp pool)
+        "cache_dtype": ("float16", "int8", "float8_e4m3fn"),
         "page_size": (64, 128, 256, 512),
         "block_kv_dec": (128, 256, 512, 1024),
     },
@@ -225,11 +225,6 @@ KERNEL_SPACES: dict[str, dict[str, tuple[int, ...]]] = {
     "rglru": {"block_d": (128, 256, 512, 1024), "chunk": (64, 128, 256)},
     "rmsnorm": {"block_rows": (64, 128, 256, 512)},
 }
-
-import jax.numpy as _jnp  # noqa: E402  (fp8 arms are platform-gated)
-
-if hasattr(_jnp, "float8_e4m3fn"):
-    KERNEL_SPACES["quantized_cache"]["cache_dtype"] += ("float8_e4m3fn",)
 
 
 def config_vmem_bytes(sig: KernelSignature, knobs: Mapping[str, int]) -> int:
@@ -390,10 +385,14 @@ def design_space(sig: KernelSignature, *,
 
 
 def default_cache_path() -> str:
+    """`REPRO_TUNER_CACHE`, else `<repo>/.tuner_cache/kernel_tuner.json`:
+    the block sizes a run compiles come from the checkout, never from a
+    file elsewhere on the host."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
     return os.environ.get(
         "REPRO_TUNER_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "kernel_tuner.json"),
+        os.path.join(root, ".tuner_cache", "kernel_tuner.json"),
     )
 
 

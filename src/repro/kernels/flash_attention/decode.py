@@ -181,12 +181,7 @@ def paged_decode_schedule(
 
 def _flash_decode_kernel(
     idx_ref,  # scalar prefetch: (B,) int32, per-request index (first token)
-    q_ref,    # (1, 1, Rp, D) — q_span tokens' folded groups, row r = s*G + g
-    k_ref,    # (1, 1, block_kv, D)
-    v_ref,
-    *rest,    # [ks_ref, vs_ref,] o_ref, m/l/acc scratch — scale refs only
-              # when `quantized` (a (1, 1) block of the fp32 per-page-per-
-              # head sidecar: one scalar scale covering this K/V block)
+    *refs,    # [tbl_ref,] [ks_ref, vs_ref,] q, k, v, o refs, m/l/acc scratch
     block_kv: int,
     kv_len: int,   # true cache length T (padding slots >= T are masked)
     window: int | None,
@@ -195,13 +190,27 @@ def _flash_decode_kernel(
     pruned: bool,
     group: int = 1,   # q rows per token (GQA fold); row // group = token off
     q_span: int = 1,  # stacked q tokens; token s sits at position index + s
+    paged: bool = False,
     quantized: bool = False,
+    kv_heads: int = 1,
+    blocks_per_page: int = 1,  # paged: streamed blocks per page (spb)
+    scale_page: int = 1,       # dense quantized: cache slots per scale row
+    scale_rows: int = 1,       # dense quantized: scale rows per (b, h)
 ):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scratch, l_scratch, acc_scratch = rest
-    else:
-        o_ref, m_scratch, l_scratch, acc_scratch = rest
+    """Scalar-prefetch operands, in order: the per-request index, then the
+    (B, num_blocks) block table when `paged`, then the flattened fp32 k/v
+    dequant scales when `quantized` — (P*K,) per page and KV head for a
+    pool, (B*K*NP,) per scale row for a dense cache.  Scales live in SMEM
+    beside the table because one scalar covers a whole streamed block, and
+    a per-block (1, 1) VMEM tile is below the TPU's (8, 128) tiling."""
+    n_pre = int(paged) + 2 * int(quantized)
+    pre = refs[:n_pre]
+    q_ref, k_ref, v_ref, o_ref, m_scratch, l_scratch, acc_scratch = \
+        refs[n_pre:]
+    tbl_ref = pre[0] if paged else None
+    ks_ref, vs_ref = pre[-2:] if quantized else (None, None)
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
 
@@ -233,14 +242,14 @@ def _flash_decode_kernel(
         if quantized:
             # dequantize in-loop at the block's page scale; the block never
             # straddles a page (block_kv | page boundary), so one scalar
-            # covers the whole tile.  Paged sidecars are (P, K) -> 2-d refs,
-            # dense ones (B, K, NP) -> 3-d refs.
-            if ks_ref.ndim == 2:
-                k = k * ks_ref[0, 0]
-                v = v * vs_ref[0, 0]
+            # covers the whole tile
+            if paged:
+                row = tbl_ref[b, ik // blocks_per_page] * kv_heads + h
             else:
-                k = k * ks_ref[0, 0, 0]
-                v = v * vs_ref[0, 0, 0]
+                row = ((b * kv_heads + h) * scale_rows
+                       + (ik * block_kv) // scale_page)
+            k = k * ks_ref[row]
+            v = v * vs_ref[row]
         s = jax.lax.dot_general(
             g, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (Gp, bkv)
@@ -272,14 +281,6 @@ def _flash_decode_kernel(
     def _fin():
         out = acc_scratch[...] / jnp.maximum(l_scratch[...], 1e-30)
         o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
-
-
-def _flash_decode_kernel_paged(idx_ref, tbl_ref, *refs, **kw):
-    """Paged variant: the block table rides in as a second scalar-prefetch
-    operand consumed *only* by the K/V index_map — every mask / softmax op
-    happens in logical slot space, so the body is the dense kernel."""
-    del tbl_ref
-    _flash_decode_kernel(idx_ref, *refs, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -321,11 +322,13 @@ def flash_decode_fwd(
 
     With `k_scale`/`v_scale`, K/V hold quantized values (int8/fp8) and the
     kernel dequantizes each streamed block at its page's fp32 scale —
-    scales ride as an extra (1, 1)-blocked operand resolved by the same
-    (table-indirected) index_map, and the fp32 online-softmax accumulation
-    is untouched.  Paged sidecars are (P, K); for dense caches pass
-    (B, NP, K)-shaped scales pre-swapped to kernel layout (B, K, NP) with
-    `scale_page` slots per scale row (block_kv is clamped to divide it)."""
+    scales ride flattened in SMEM as scalar-prefetch operands, looked up
+    per streamed block through the same table indirection as K/V, and the
+    fp32 online-softmax accumulation is untouched.  SMEM holds 1 MiB, so
+    a pool may carry about 120k (page, KV head) scale pairs.  Paged
+    sidecars are (P, K); for dense caches pass (B, NP, K)-shaped scales
+    pre-swapped to kernel layout (B, K, NP) with `scale_page` slots per
+    scale row (block_kv is clamped to divide it)."""
     B, K, R, D = q.shape
     if R % q_span:
         raise ValueError(f"q rows {R} not divisible by q_span={q_span}")
@@ -361,7 +364,7 @@ def flash_decode_fwd(
 
     # TPU sublane tiling wants >= 8 q rows; pad the folded rows (the padded
     # rows compute garbage that is sliced off — rows are softmax-independent).
-    Rp = max(8, R) if not interpret else R
+    Rp = max(8, R)
     if Rp != R:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
 
@@ -390,60 +393,44 @@ def flash_decode_fwd(
             return jnp.minimum(lo + j, hi - 1)
         return j
 
+    # scalar-prefetch operands: index, [table,] [k/v scales]; every
+    # index_map receives all of them after the grid indices
+    prefetch = (index,)
     if paged:
-        def kv_index(b, h, j, idx_ref, tbl_ref):
+        prefetch += (tables,)
+    if quantized:
+        prefetch += (jnp.asarray(k_scale, jnp.float32).reshape(-1),
+                     jnp.asarray(v_scale, jnp.float32).reshape(-1))
+    scale_rows = 1 if paged or not quantized else k_scale.shape[-1]
+
+    if paged:
+        def kv_index(b, h, j, idx_ref, tbl_ref, *_):
             jb = logical_block(b, j, idx_ref)
             return (tbl_ref[b, jb // spb], h, jb % spb, 0)
-
-        def qo_index(b, h, j, idx_ref, tbl_ref):
-            return (b, h, 0, 0)
-
-        def sc_index(b, h, j, idx_ref, tbl_ref):
-            # same table indirection as kv_index, at page granularity
-            jb = logical_block(b, j, idx_ref)
-            return (tbl_ref[b, jb // spb], h)
-
-        scale_block = (1, 1)
-        kernel_fn = _flash_decode_kernel_paged
-        num_prefetch = 2
-        operands = (index, tables, q, k, v)
     else:
-        def kv_index(b, h, j, idx_ref):
+        def kv_index(b, h, j, idx_ref, *_):
             return (b, h, logical_block(b, j, idx_ref), 0)
 
-        def qo_index(b, h, j, idx_ref):
-            return (b, h, 0, 0)
-
-        def sc_index(b, h, j, idx_ref):
-            jb = logical_block(b, j, idx_ref)
-            return (b, h, (jb * block_kv) // scale_page)
-
-        scale_block = (1, 1, 1)
-        kernel_fn = _flash_decode_kernel
-        num_prefetch = 1
-        operands = (index, q, k, v)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, Rp, D), qo_index),
-        pl.BlockSpec((1, 1, block_kv, D), kv_index),
-        pl.BlockSpec((1, 1, block_kv, D), kv_index),
-    ]
-    if quantized:
-        in_specs += [pl.BlockSpec(scale_block, sc_index)] * 2
-        operands = operands + (jnp.asarray(k_scale, jnp.float32),
-                               jnp.asarray(v_scale, jnp.float32))
+    def qo_index(b, h, j, *_):
+        return (b, h, 0, 0)
 
     kernel = functools.partial(
-        kernel_fn,
+        _flash_decode_kernel,
         block_kv=block_kv, kv_len=T, window=window,
         softcap=softcap, scale=1.0 / np.sqrt(D), pruned=pruned,
-        group=G, q_span=q_span, quantized=quantized,
+        group=G, q_span=q_span, paged=paged, quantized=quantized,
+        kv_heads=K, blocks_per_page=spb if paged else 1,
+        scale_page=int(scale_page or 1), scale_rows=int(scale_rows),
     )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_prefetch,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, K, steps),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((1, 1, Rp, D), qo_index),
+            pl.BlockSpec((1, 1, block_kv, D), kv_index),
+            pl.BlockSpec((1, 1, block_kv, D), kv_index),
+        ],
         out_specs=pl.BlockSpec((1, 1, Rp, D), qo_index),
         scratch_shapes=[
             pltpu.VMEM((Rp, 1), jnp.float32),
@@ -456,7 +443,7 @@ def flash_decode_fwd(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, Rp, D), q.dtype),
         interpret=interpret,
-    )(*operands)
+    )(*prefetch, q, k, v)
     return out[:, :, :R, :]
 
 

@@ -298,7 +298,7 @@ def _finalize(o_ref, lse_ref, m_scratch, l_scratch, acc_scratch):
         # per-row softmax stats for the fused backward: lse = m + log(l).
         # Fully-masked rows keep lse ~ NEG_INF so the backward's
         # exp(s_masked - lse) stays finite (see _bwd_p_ds).
-        lse_ref[0, 0, :] = (m + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
+        lse_ref[0, 0] = m + jnp.log(jnp.maximum(l, 1e-30))
 
 
 def _init_scratch(m_scratch, l_scratch, acc_scratch):
@@ -483,10 +483,12 @@ def flash_attention_fwd(
     ]
     out_shape = [jax.ShapeDtypeStruct((B, H, Sp, D), q.dtype)]
     if return_lse:  # inference-only calls skip the lse compute + HBM write
-        out_specs.append(
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, j: (b, h, iq))
-        )
-        out_shape.append(jax.ShapeDtypeStruct((B, H, Sp), jnp.float32))
+        # row stats ride as a trailing unit dim: a (block_q, 1) column block
+        # is sublane-aligned and full in the last dim, which the TPU's
+        # (8, 128) block tiling accepts (a bare (block_q,) row is not)
+        out_specs.append(pl.BlockSpec((1, 1, block_q, 1),
+                                      lambda b, h, iq, j: (b, h, iq, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, Sp, 1), jnp.float32))
     res = pl.pallas_call(
         kernel,
         grid=grid,
@@ -506,7 +508,7 @@ def flash_attention_fwd(
     )(q, k, v)
     if return_lse:
         out, lse = res
-        return out[:, :, :S, :], lse[:, :, :S]
+        return out[:, :, :S, :], lse[:, :, :S, 0]
     (out,) = res
     return out[:, :, :S, :]
 
@@ -545,12 +547,12 @@ def _bwd_p_ds(
     # and exp(NEG_INF - NEG_INF) = 1 is finite (then zeroed by the mask),
     # whereas exp(real - NEG_INF) would overflow to inf * 0 = nan.
     s_masked = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s_masked - lse[:, None]) * maskf
+    p = jnp.exp(s_masked - lse) * maskf
 
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     if softcap is not None:  # d/dx [c*tanh(x/c)] = 1 - tanh^2 = 1 - (s/c)^2
         ds = ds * (1.0 - jnp.square(s / softcap))
     return p, ds
@@ -725,13 +727,14 @@ def flash_attention_bwd(
     q = _pad_to(q, 2, block_q)
     out = _pad_to(out, 2, block_q)
     do = _pad_to(do, 2, block_q)
-    lse = _pad_to(lse, 2, block_q)
+    lse = _pad_to(lse, 2, block_q)[..., None]  # (B, H, Sp, 1) columns
     k = _pad_to(k, 2, block_kv)
     v = _pad_to(v, 2, block_kv)
     Sp, Tp = q.shape[2], k.shape[2]
     nq, nk = Sp // block_q, Tp // block_kv
 
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)
     scale = 1.0 / np.sqrt(D)
     use_pruned = pruned and causal
 
@@ -751,9 +754,6 @@ def flash_attention_bwd(
     def q_row(b, h, iq, j):
         return (b, h, iq, 0)
 
-    def q_stat(b, h, iq, j):
-        return (b, h, iq)
-
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel,
         block_q=block_q, block_kv=block_kv, kv_len=T, nk=nk,
@@ -768,8 +768,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, block_kv, D), kv_index),
             pl.BlockSpec((1, 1, block_kv, D), kv_index),
             pl.BlockSpec((1, 1, block_q, D), q_row),
-            pl.BlockSpec((1, 1, block_q), q_stat),
-            pl.BlockSpec((1, 1, block_q), q_stat),
+            pl.BlockSpec((1, 1, block_q, 1), q_row),
+            pl.BlockSpec((1, 1, block_q, 1), q_row),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), q_row),
         out_shape=jax.ShapeDtypeStruct((B, H, Sp, D), q.dtype),
@@ -794,9 +794,6 @@ def flash_attention_bwd(
             jq = jnp.minimum(lo + jq, jnp.maximum(hi - 1, lo))
         return (b, h, jq, 0)
 
-    def q_stat_t(b, kh, ik, j):
-        return q_index(b, kh, ik, j)[:3]
-
     def kv_row(b, kh, ik, j):
         return (b, kh, ik, 0)
 
@@ -814,8 +811,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, block_kv, D), kv_row),
             pl.BlockSpec((1, 1, block_kv, D), kv_row),
             pl.BlockSpec((1, 1, block_q, D), q_index),
-            pl.BlockSpec((1, 1, block_q), q_stat_t),
-            pl.BlockSpec((1, 1, block_q), q_stat_t),
+            pl.BlockSpec((1, 1, block_q, 1), q_index),
+            pl.BlockSpec((1, 1, block_q, 1), q_index),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_kv, D), lambda b, kh, ik, j: (b, kh, ik, 0)),

@@ -1,10 +1,11 @@
 """Jit'd public wrapper for the flash-attention kernel.
 
 Accepts model-layout tensors (B, S, H, D) / (B, T, K, D), handles the
-(B, H, S, D) kernel layout, interpret-mode fallback on non-TPU backends, and
-optional shard_map distribution: batch over the data(/pod) axes and q-heads
-over the model axis when divisible (KV heads are gathered per local q head
-inside each shard, so the kernel always runs a per-device dense problem).
+(B, H, S, D) kernel layout, runs compiled on a TPU and in interpret mode on
+the CPU (`repro.kernels.interpret_default`), and optional shard_map
+distribution: batch over the data(/pod) axes and q-heads over the model
+axis when divisible (KV heads are gathered per local q head inside each
+shard, so the kernel always runs a per-device dense problem).
 
 Block sizes left unspecified (None) are resolved from the kernel-tuner
 cache (repro.autotune.kernel_tuner) keyed by the problem signature, falling
@@ -29,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import interpret_default
 from repro.kernels.flash_attention.kernel import (
     flash_attention_bwd,
     flash_attention_fwd,
@@ -41,12 +43,8 @@ DEFAULT_PAGE_SIZE = 128
 
 # Quantized KV-cache dtypes: name -> largest representable magnitude.  The
 # per-page-per-head scale is abs_max / qmax, so dequant is value * scale.
-# fp8 entries appear only when the installed jax ships the dtype.
-CACHE_QMAX: dict[str, float] = {"int8": 127.0}
-if hasattr(jnp, "float8_e4m3fn"):
-    CACHE_QMAX["float8_e4m3fn"] = 448.0
-if hasattr(jnp, "float8_e5m2"):
-    CACHE_QMAX["float8_e5m2"] = 57344.0
+CACHE_QMAX: dict[str, float] = {"int8": 127.0, "float8_e4m3fn": 448.0,
+                                "float8_e5m2": 57344.0}
 
 
 def cache_qmax(dtype) -> float:
@@ -63,9 +61,7 @@ def resolve_cache_dtype(name):
     name = str(name)
     if name not in CACHE_QMAX:
         return None
-    return {"int8": jnp.int8,
-            "float8_e4m3fn": getattr(jnp, "float8_e4m3fn", None),
-            "float8_e5m2": getattr(jnp, "float8_e5m2", None)}[name]
+    return jnp.dtype(name)
 
 
 def kv_scale_from_absmax(absmax, dtype):
@@ -92,18 +88,6 @@ def quantize_kv_write(x, scale, dtype):
 def dequantize_kv(x, scale):
     """fp32 dequant of (..., K, D) quantized values at (..., K) scales."""
     return x.astype(jnp.float32) * jnp.asarray(scale, jnp.float32)[..., None]
-
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -215,7 +199,7 @@ def flash_attention(
     rules: Mapping[str, Any] | None = None,
 ) -> jax.Array:
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     block_q, block_kv, block_q_bwd, block_kv_bwd = _resolve_blocks(
         q, k, causal=causal, window=window, block_q=block_q, block_kv=block_kv,
         block_q_bwd=block_q_bwd, block_kv_bwd=block_kv_bwd,
@@ -253,11 +237,11 @@ def flash_attention(
             v_l = jnp.take(v_l, idx, axis=2)
         return call(q_l, k_l, v_l)
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return shard(q, k, v)
 
@@ -406,7 +390,7 @@ def flash_decode(
     single-token call, so S=1 and sequential decode stay bit-identical.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     index = jnp.asarray(index, jnp.int32)
     if tables is not None:
         if kv_len is None:

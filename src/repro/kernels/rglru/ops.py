@@ -7,11 +7,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.rglru.kernel import rglru_fwd
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "chunk", "interpret"))
@@ -26,7 +23,7 @@ def rglru_pallas(a, b, h0, *, block_d: int = 512, chunk: int = 256,
                  interpret: bool | None = None):
     """a,b: (B,S,D); h0: (B,D). Returns (h_seq (B,S,D) fp32, h_last (B,D))."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     B, S, D = a.shape
     block_d = min(block_d, D)
     while D % block_d:

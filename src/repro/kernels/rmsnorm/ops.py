@@ -6,11 +6,8 @@ import functools
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.rmsnorm.kernel import rmsnorm_fwd
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
@@ -21,5 +18,5 @@ def _rmsnorm_jit(x, w, *, eps, block_rows, interpret):
 def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: int = 256,
             interpret: bool | None = None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     return _rmsnorm_jit(x, w, eps=eps, block_rows=block_rows, interpret=interpret)

@@ -7,11 +7,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.rwkv6.kernel import wkv_fwd
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -27,7 +24,7 @@ def _wkv_jit(r, k, v, w, u, s0, *, chunk, interpret):
 def wkv_pallas(r, k, v, w, u, s0, *, chunk: int = 32, interpret: bool | None = None):
     """r,k,v,w: (B,S,H,C); u: (H,C); s0: (B,H,C,C). Returns (y, s_last)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     S = r.shape[1]
     chunk = min(chunk, S)
     if S % chunk:  # pad to a chunk multiple; padded steps have w=1, k=0

@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import SHAPES
 from repro.core.program import Program
 from repro.distributed.sharding import input_shardings, param_shardings
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.weave import default_weave
 from repro.models.registry import ARCHS, cells, get_config, input_specs, skipped_cells
@@ -209,6 +210,7 @@ def main() -> int:
                     help="weave override key=value (JSON values)")
     ap.add_argument("--suffix", default="", help="artifact filename suffix")
     args = ap.parse_args()
+    use_compile_cache()
 
     overrides = _parse_set(args.sets)
     meshes = {"pod": [False], "multipod": [True], "both": [False, True]}[args.mesh]

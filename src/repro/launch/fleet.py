@@ -26,6 +26,7 @@ from repro.configs.base import SHAPES
 from repro.core.program import Program
 from repro.core.strategies.resilience import FaultInjector
 from repro.distributed.fault import PreemptionHandler
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.weave import default_weave
 from repro.models.registry import ARCHS
 from repro.runtime.fleet import ServingFleet
@@ -51,6 +52,7 @@ def main() -> int:
                     help="SIGTERM-drain replica HOST mid-wave")
     ap.add_argument("--deadline-s", type=float, default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     program = Program.from_arch(args.arch, kind="serve", reduced=True)
     woven = default_weave(program, SHAPES["prefill_32k"], {})

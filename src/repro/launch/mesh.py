@@ -12,16 +12,10 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API; older versions are Auto-only anyway
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _axis_types(n: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n}
 
 

@@ -1,6 +1,12 @@
-"""Serving launcher (reduced-config CPU demo of the serve path).
+"""Serving launcher: the serve path at the architecture's published widths.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --requests 4
+    PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --stream
+    PYTHONPATH=src python -m repro.launch.serve --reduced --stream   # CPU demo
+
+`--reduced` builds the architecture's small smoke config, which runs on
+the CPU in seconds; without it the full model is built (yi-6b: ~12 GB of
+bf16 params, one TPU v5e).  The exit status is non-zero when any request
+of a continuous, stream or fleet serve ends in an outcome other than "ok".
 
 Four modes:
   (default)     legacy solo `serve()` per request;
@@ -24,6 +30,7 @@ import numpy as np
 
 from repro.configs.base import SHAPES
 from repro.core.program import Program
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.weave import default_weave
 from repro.models.registry import ARCHS
 from repro.runtime.server import Server, ServerConfig
@@ -36,9 +43,19 @@ def _print_outcomes(outcomes, outputs) -> None:
               f"{rep}" + (f"  ({o['reason']})" if o["reason"] else ""))
 
 
+def _all_ok(outcomes) -> int:
+    bad = [o for o in outcomes if o["status"] != "ok"]
+    if bad:
+        print(f"{len(bad)} of {len(outcomes)} request(s) not ok")
+    return 1 if bad else 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="yi-6b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="build the small smoke config (CPU demo) instead "
+                         "of the published widths")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -59,8 +76,10 @@ def main() -> int:
                     help="route the wave across N fleet replicas "
                          "(implies --continuous)")
     args = ap.parse_args()
+    use_compile_cache()
 
-    program = Program.from_arch(args.arch, kind="serve", reduced=True)
+    program = Program.from_arch(args.arch, kind="serve",
+                                reduced=args.reduced)
     woven = default_weave(program, SHAPES["prefill_32k"], {})
     cfg = ServerConfig(
         max_cache_len=args.prompt_len + args.decode_tokens + 1,
@@ -81,7 +100,7 @@ def main() -> int:
               f"{stats['rounds']} round(s); affinity hits "
               f"{stats['affinity_hits']}")
         _print_outcomes(fleet.last_outcomes, outs)
-        return 0
+        return _all_ok(fleet.last_outcomes)
 
     server = Server(woven, cfg)
     if args.stream:
@@ -138,7 +157,7 @@ def main() -> int:
                   f"{q['distinct_ops']} distinct OP(s), "
                   f"objective={q['objective']}, "
                   f"energy={q['energy_j']:.1f}J")
-        return 0
+        return _all_ok(server.last_outcomes)
 
     if args.continuous:
         prompts = [rng.integers(0, program.cfg.vocab, args.prompt_len)
@@ -149,7 +168,7 @@ def main() -> int:
               f"{server.last_pool_stats['live_pages']} live pages, "
               f"{server.last_pool_stats['prefix_hits']} prefix hits")
         _print_outcomes(server.last_outcomes, outs)
-        return 0
+        return _all_ok(server.last_outcomes)
 
     for i in range(args.requests):
         prompt = rng.integers(0, program.cfg.vocab,

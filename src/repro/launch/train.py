@@ -18,6 +18,7 @@ import jax
 from repro.configs.base import SHAPES
 from repro.core.program import Program
 from repro.data.pipeline import PipelineConfig, TokenPipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.weave import default_weave
 from repro.models.registry import ARCHS
 from repro.runtime.trainer import Trainer, TrainerConfig
@@ -37,6 +38,7 @@ def main() -> int:
     ap.add_argument("--max-retries", type=int, default=2)
     ap.add_argument("--set", dest="sets", action="append", default=[])
     args = ap.parse_args()
+    use_compile_cache()
 
     overrides = {}
     for kv in args.sets:

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import fnmatch
+import functools
 import zlib
 from typing import Any, Callable, Iterator, Mapping, Sequence
 
@@ -54,26 +55,44 @@ class ParamSpec:
             )
 
     def instantiate(self, key: jax.Array, policy: DTypePolicy) -> jax.Array:
+        """Draw this tensor on the device that holds `key`.  One jitted
+        program per spec: the fp32 normal draw fuses into the cast, so a
+        stacked (layers, d, f) leaf never has a full-size fp32 transient."""
         dtype = self.dtype if self.dtype is not None else policy.param_dtype
-        if self.init == "zeros":
-            return jnp.zeros(self.shape, dtype)
-        if self.init == "ones":
-            return jnp.ones(self.shape, dtype)
-        if self.init == "normal":
-            std = self.scale if self.scale is not None else 0.02
-            return (jax.random.normal(key, self.shape, jnp.float32) * std).astype(dtype)
-        if self.init == "scaled":  # 1/sqrt(fan_in) truncated-normal-ish
-            fan_in = self.scale if self.scale is not None else self.shape[0]
-            std = 1.0 / np.sqrt(max(fan_in, 1))
-            return (jax.random.normal(key, self.shape, jnp.float32) * std).astype(dtype)
-        if self.init == "embedding":
-            std = self.scale if self.scale is not None else 1.0
-            return (jax.random.normal(key, self.shape, jnp.float32) * std).astype(dtype)
-        raise ValueError(f"unknown initializer {self.init!r}")
+        if self.init not in ("zeros", "ones", "normal", "scaled", "embedding"):
+            raise ValueError(f"unknown initializer {self.init!r}")
+        return _draw(key, spec=self, dtype=jnp.dtype(dtype))
 
     def shape_dtype(self, policy: DTypePolicy) -> jax.ShapeDtypeStruct:
         dtype = self.dtype if self.dtype is not None else policy.param_dtype
         return jax.ShapeDtypeStruct(self.shape, dtype)
+
+
+# keep_unused: zeros/ones never read the key, but it names the device
+@functools.partial(jax.jit, static_argnames=("spec", "dtype"),
+                   keep_unused=True)
+def _draw(key: jax.Array, *, spec: ParamSpec, dtype) -> jax.Array:
+    if spec.init == "zeros":
+        return jnp.zeros(spec.shape, dtype)
+    if spec.init == "ones":
+        return jnp.ones(spec.shape, dtype)
+    if spec.init == "normal":
+        std = spec.scale if spec.scale is not None else 0.02
+    elif spec.init == "scaled":  # 1/sqrt(fan_in) truncated-normal-ish
+        fan_in = spec.scale if spec.scale is not None else spec.shape[0]
+        std = 1.0 / np.sqrt(max(fan_in, 1))
+    else:  # "embedding"
+        std = spec.scale if spec.scale is not None else 1.0
+
+    def normal(k, shape):
+        return (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
+
+    if spec.axes and spec.axes[0] == "layers":
+        # a stacked (layers, ...) leaf draws one layer slice per key: the
+        # TPU compiler builds that ~10x faster than one whole-stack draw
+        keys = jax.random.split(key, spec.shape[0])
+        return jax.lax.map(lambda k: normal(k, spec.shape[1:]), keys)
+    return normal(key, spec.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -261,10 +280,14 @@ def flatten_specs(module: Module) -> dict[str, ParamSpec]:
 
 
 def init_params(
-    module: Module, key: jax.Array, policies: PolicyResolver | None = None
+    module: Module, key: jax.Array, policies: PolicyResolver | None = None,
+    *, device: jax.Device | None = None,
 ) -> dict[str, Any]:
-    """Materialize the parameter pytree (nested dicts keyed by module names)."""
+    """Materialize the parameter pytree (nested dicts keyed by module names),
+    leaf by leaf on `device` (default: JAX's default device)."""
     policies = policies or PolicyResolver.default()
+    if device is not None:
+        key = jax.device_put(key, device)
 
     def leaf(spec: ParamSpec, path: str):
         return spec.instantiate(_key_for(path, key), policies.resolve(path))

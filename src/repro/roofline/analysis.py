@@ -29,12 +29,8 @@ from typing import Any
 from repro.roofline.hw import DTYPE_BYTES, HBM_BW, ICI_LINK_BW, PEAK_FLOPS_BF16
 
 def cost_properties(compiled) -> dict:
-    """Normalized `compiled.cost_analysis()`: newer jax returns a dict,
-    older versions a one-element list of dicts."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """`compiled.cost_analysis()` as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 
 _COLL_RE = re.compile(

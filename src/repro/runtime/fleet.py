@@ -104,8 +104,11 @@ class ServingFleet:
 
     `factory` builds one replica's Server; replicas built from one shared
     WovenProgram share jit caches, which is exactly what N processes from
-    one container image would do.  Policy knobs left None resolve from the
-    woven `fleet_resilience` extras (FleetResilienceAspect), then from
+    one container image would do.  With `devices`, replica (and spare) i
+    is built as `factory(device=devices[i % len(devices)])`, so each
+    replica's params and page pool live on its own chip.  Policy knobs
+    left None resolve from the woven `fleet_resilience` extras
+    (FleetResilienceAspect), then from
     `DEFAULT_FLEET_POLICY`; an explicit `injector` (or the woven
     `fleet_injector`) arms the fleet join points.
     """
@@ -121,12 +124,19 @@ class ServingFleet:
                  wave_size: int | None = None,
                  dead_after_rounds: float | None = None,
                  kill_step: int | None = None,
-                 digest_page_size: int = 8):
+                 digest_page_size: int = 8,
+                 devices: list | None = None):
         if replicas < 1:
             raise ValueError("a fleet needs at least one replica")
         self.factory = factory
-        self.replicas = [Replica(h, factory()) for h in range(replicas)]
-        self.spares = deque(Replica(replicas + j, factory())
+
+        def build(h: int) -> Server:
+            if devices:
+                return factory(device=devices[h % len(devices)])
+            return factory()
+
+        self.replicas = [Replica(h, build(h)) for h in range(replicas)]
+        self.spares = deque(Replica(replicas + j, build(replicas + j))
                             for j in range(spares))
         probe = self.replicas[0].server.woven.state.extra
         pol = dict(DEFAULT_FLEET_POLICY)

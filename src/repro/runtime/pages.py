@@ -330,7 +330,8 @@ class PagedCacheManager:
     def __init__(self, num_pages: int, page_size: int, *,
                  max_len: int | None = None, window: int | None = None,
                  prefix_sharing: bool = True,
-                 cache_dtype: str | None = None):
+                 cache_dtype: str | None = None,
+                 device: jax.Device | None = None):
         from repro.kernels.flash_attention.ops import resolve_cache_dtype
 
         self.pool = PagePool(num_pages, page_size)
@@ -342,6 +343,7 @@ class PagedCacheManager:
         # dtype plus fp32 per-page-per-head scale sidecars.  Unknown / fp
         # names resolve to None — the pool stays at the model dtype.
         self.cache_dtype = resolve_cache_dtype(cache_dtype)
+        self.device = device  # where the pools live (None: default device)
         self._pools: dict[str, dict[str, jax.Array]] = {}
         self._groups: dict[str, dict[str, Any]] = {}  # structure, 1st admit
         self._meta: dict[Any, dict[str, Any]] = {}    # per-request state
@@ -496,14 +498,15 @@ class PagedCacheManager:
             if info["scanned"]:
                 shape = (info["n"], *shape)
                 sshape = (info["n"], *sshape)
+            dev = self.device
             pools = {
-                "pk": jnp.zeros(shape, qdt or info["dtype"]),
-                "pv": jnp.zeros(shape, qdt or info["dtype"]),
+                "pk": jnp.zeros(shape, qdt or info["dtype"], device=dev),
+                "pv": jnp.zeros(shape, qdt or info["dtype"], device=dev),
             }
             if qdt is not None:
                 # fp32 per-page-per-head dequant scales; 0.0 = free page
-                pools["ksc"] = jnp.zeros(sshape, jnp.float32)
-                pools["vsc"] = jnp.zeros(sshape, jnp.float32)
+                pools["ksc"] = jnp.zeros(sshape, jnp.float32, device=dev)
+                pools["vsc"] = jnp.zeros(sshape, jnp.float32, device=dev)
             self._pools[name] = pools
 
     @property

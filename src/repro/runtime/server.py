@@ -83,9 +83,13 @@ class ServerConfig:
 class Server:
     def __init__(self, woven: WovenProgram, cfg: ServerConfig, *, mesh=None,
                  margot=None, broker: ExamonBroker | None = None,
-                 memo: MemoTable | None = None, draft: "Server | None" = None):
+                 memo: MemoTable | None = None, draft: "Server | None" = None,
+                 device: jax.Device | None = None):
         self.woven = woven
         self.cfg = cfg
+        # the device holding this replica's params and page pool (None:
+        # JAX's default device); every step runs where its params live
+        self.device = device
         # draft server for speculative decoding (registry `draft_for`
         # pairing, or any Server over the same vocab); None self-drafts
         # when a draft_len is requested
@@ -135,7 +139,9 @@ class Server:
                     return jax.jit(fn, donate_argnums=(2,))
                 return jax.jit(fn)
 
-            return LibVC(builder, error_strategy="fallback")
+            # a variant that fails to build raises: falling back to the
+            # default would serve a different program than the one asked for
+            return LibVC(builder, error_strategy="exit")
 
         self.prefill_vc = build("prefill")
         self.decode_vc = build("decode")
@@ -143,7 +149,7 @@ class Server:
         self.paged_prefill_vc = build("paged_prefill")
         self.rescore_vc = build("rescore")
         self.params = init_params(woven.program.model, jax.random.PRNGKey(cfg.seed),
-                                  woven.state.policies)
+                                  woven.state.policies, device=device)
         self.served = 0
         # latency histories are sliding windows (deques), not unbounded
         # lists: a long-running serve_stream session appends per wave, and
@@ -861,7 +867,7 @@ class Server:
         manager = PagedCacheManager(
             pool_pages, ps, max_len=self.cfg.max_cache_len,
             window=getattr(self.woven.program.cfg, "attn_window", None),
-            prefix_sharing=share, cache_dtype=cache_dtype,
+            prefix_sharing=share, cache_dtype=cache_dtype, device=self.device,
         )
         # feedback observations are per-knob-setting: start a fresh window,
         # bucketed by batch size (a decode step's cost scales with the live
@@ -889,6 +895,7 @@ class Server:
                 window=getattr(draft_srv.woven.program.cfg,
                                "attn_window", None),
                 prefix_sharing=False, cache_dtype=cache_dtype,
+                device=draft_srv.device,
             )
 
         # logical-clock arrivals: requests with a future arrival wave sit

@@ -290,6 +290,27 @@ class TestServingFleet:
         assert len(st["replicas_with_prefix_hits"]) >= 2
         assert st["affinity_hits"] > 0
 
+    def test_devices_place_each_replica(self, woven, baseline):
+        """`devices` builds replica i on devices[i % n]: its params, and
+        the page pool its serve allocates, live there, and the output
+        still matches the single-server baseline."""
+        import jax
+
+        from repro.runtime.server import Server, ServerConfig
+
+        prompts, base = baseline
+        dev = jax.devices()[-1]
+        fleet = ServingFleet(
+            lambda **kw: Server(woven, ServerConfig(
+                max_cache_len=24, decode_tokens=4, max_batch=2,
+                page_size=8), **kw),
+            replicas=2, spares=1, wave_size=3, devices=[dev])
+        for rep in list(fleet.replicas) + list(fleet.spares):
+            assert rep.server.device == dev
+            assert {d for x in jax.tree.leaves(rep.server.params)
+                    for d in x.devices()} == {dev}
+        assert _parity(fleet.serve(prompts, decode_tokens=4), base)
+
     def test_kill_midwave_recovers_with_parity(self, factory, baseline):
         prompts, base = baseline
         inj = FaultInjector.single("replica_loss", "raise", at=1)

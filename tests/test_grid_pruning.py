@@ -222,8 +222,11 @@ class TestGroupLocalDkv:
 
         def spy(kernel, *args, **kw):
             out_shape = kw.get("out_shape")
+            # dk/dv: two (B, K, T, D) outputs; the forward's (out, lse)
+            # pair ends in a unit column dim
             if (isinstance(out_shape, list) and len(out_shape) == 2
-                    and all(len(s.shape) == 4 for s in out_shape)):
+                    and all(len(s.shape) == 4 and s.shape[-1] > 1
+                            for s in out_shape)):
                 captured.append([tuple(s.shape) for s in out_shape])  # dk, dv
             return real(kernel, *args, **kw)
 

@@ -178,3 +178,18 @@ class TestFlashAttentionGrad:
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_default_by_backend(monkeypatch, backend, interpret):
+    """Kernels interpret on the CPU, compile on a TPU, and refuse any other
+    backend instead of silently running interpreted."""
+    from repro.kernels import interpret_default
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            interpret_default()
+    else:
+        assert interpret_default() is interpret
