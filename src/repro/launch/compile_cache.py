@@ -5,9 +5,18 @@
 
 Called first thing by `chip_smoke.py` and the `repro.launch` entry points,
 never on import of `repro`.  A `JAX_COMPILATION_CACHE_DIR` set in the
-environment wins, and then nothing is set in code: JAX reads the variable
-itself.  Otherwise the cache lives at the fixed `<repo>/.jax_cache`, so a
-later process on the same checkout finds what an earlier one compiled.
+environment wins, and then no directory is set in code: JAX reads the
+variable itself.  Otherwise the cache lives at the fixed `<repo>/.jax_cache`,
+so a later process on the same checkout finds what an earlier one compiled.
+
+Either way the cache key keeps each operation's metadata, its join-point
+name stack among it (`nn.module._Scope`): by default JAX strips it, and an
+executable that some other version of the program compiled, alike but for
+its names, would load with stale names and a profile would misplace the
+device time.  Each operation's location keeps one frame of its traceback,
+its own, so the key does not change with the call stack above a jitted
+step: a process that warms up the programs and one that serves them find
+the same entries.
 """
 
 from __future__ import annotations
@@ -23,6 +32,8 @@ DEFAULT_CACHE_DIR = REPO_ROOT / ".jax_cache"
 
 def use_compile_cache() -> tuple[str, bool]:
     """Returns (cache directory, whether it came from the environment)."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env, True

@@ -182,14 +182,22 @@ class Ctx:
 
 
 class _Scope:
+    """One join point of the module path.  It also names the operations
+    traced inside it (`jax.named_scope`), so each HLO op carries the DSL
+    path (`.../blocks0/block/attn/...`) in its `op_name` metadata and a
+    profiler trace can put device time down to join points."""
+
     def __init__(self, ctx: Ctx, name: str):
         self.ctx, self.name = ctx, name
+        self._named = jax.named_scope(name)
 
     def __enter__(self):
         self.ctx._path.append(self.name)
+        self._named.__enter__()
         return self.ctx
 
     def __exit__(self, *exc):
+        self._named.__exit__(*exc)
         self.ctx._path.pop()
         return False
 

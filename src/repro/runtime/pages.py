@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.flash_attention.kernel import cdiv
+from repro.monitor.spans import SpanRecorder, spanned
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -331,7 +332,8 @@ class PagedCacheManager:
                  max_len: int | None = None, window: int | None = None,
                  prefix_sharing: bool = True,
                  cache_dtype: str | None = None,
-                 device: jax.Device | None = None):
+                 device: jax.Device | None = None,
+                 spans: SpanRecorder | None = None):
         from repro.kernels.flash_attention.ops import resolve_cache_dtype
 
         self.pool = PagePool(num_pages, page_size)
@@ -344,6 +346,9 @@ class PagedCacheManager:
         # names resolve to None — the pool stays at the model dtype.
         self.cache_dtype = resolve_cache_dtype(cache_dtype)
         self.device = device  # where the pools live (None: default device)
+        # host time of the pool's work by phase (the `pages.*` spans); the
+        # serving loop hands over its own recorder
+        self.spans = spans if spans is not None else SpanRecorder()
         self._pools: dict[str, dict[str, jax.Array]] = {}
         self._groups: dict[str, dict[str, Any]] = {}  # structure, 1st admit
         self._meta: dict[Any, dict[str, Any]] = {}    # per-request state
@@ -409,6 +414,7 @@ class PagedCacheManager:
         return sum(1 for i in range(lo, hi)
                    if self.pool.refcount(table[i]) > 1)
 
+    @spanned("pages.admit")
     def can_admit(self, final_len: int, tokens=None) -> bool:
         """Admission control: free pages must cover this request's worst
         case — *new* pages only: a matched prompt prefix rides on shared
@@ -606,6 +612,7 @@ class PagedCacheManager:
     def _table_row(self, rid) -> jax.Array:
         return jnp.asarray(self.pool.table_rows([rid], self.table_width))
 
+    @spanned("pages.admit")
     def admit_begin(self, rid, tokens, *, final_len: int,
                     shared_pages: Sequence[int] = (),
                     shared_len: int = 0):
@@ -638,6 +645,7 @@ class PagedCacheManager:
         self._new_meta(rid, S, final_len)
         return self.prefill_view(rid, start), start
 
+    @spanned("pages.prefill_view")
     def prefill_view(self, rid, resident: int) -> dict:
         """Single-request paged *prefill* cache view with ``index`` pinned
         at `resident` tokens already pool-written — the view `admit_begin`
@@ -666,6 +674,7 @@ class PagedCacheManager:
         view["block_tables"] = self._table_row(rid)
         return view
 
+    @spanned("pages.absorb_prefill")
     def absorb_prefill(self, rid, new_cache: dict) -> None:
         """Absorb one prefill *chunk*'s pool writes (pk/pv plus the scale
         sidecars, ring write positions) without registering the prompt —
@@ -678,6 +687,7 @@ class PagedCacheManager:
             if info["ring"]:
                 meta["pos"][name] = group["pos"]  # (W,) or (n, W)
 
+    @spanned("pages.admit")
     def admit_finish(self, rid, new_cache: dict, tokens) -> None:
         """Absorb the paged-prefill step's outputs (pools now hold the
         suffix K/V) and register the prompt in the prefix index."""
@@ -694,6 +704,7 @@ class PagedCacheManager:
                 state[key] = group[key]
         return state
 
+    @spanned("pages.admit")
     def admit_shared(self, rid, tokens, *, final_len: int,
                      pages: Sequence[int]) -> None:
         """Admit a full-prompt prefix hit: every prompt page is already
@@ -713,6 +724,7 @@ class PagedCacheManager:
         self.prefix_hits += len(pages)
         self._new_meta(rid, S, final_len)
 
+    @spanned("pages.prefill_view")
     def rescore_view(self, rid) -> dict:
         """Single-request decode cache view with index = length - 1: the
         no-write re-score of the last prompt token that yields a shared-
@@ -814,12 +826,14 @@ class PagedCacheManager:
             meta["kv_pos"] = cache["kv_pos"][0]  # (max_len,)
         self._meta[rid] = meta
 
+    @spanned("pages.release")
     def retire(self, rid) -> None:
         freed = self.pool.release(rid)
         self._purge_keys(freed)
         self._pop_scales(freed)
         del self._meta[rid]
 
+    @spanned("pages.release")
     def abort(self, rid) -> None:
         """Best-effort rollback of a partial admission (or a forced
         eviction): release the request's pages if it holds any and drop
@@ -879,6 +893,7 @@ class PagedCacheManager:
                     pools["vsc"] = _copy_scale_row(pools["vsc"], old, new)
             self.cow_splits += 1
 
+    @spanned("pages.compose")
     def batch(self, rids: list[Any], *, tokens: int = 1) -> dict:
         """Decode cache pytree for this step's active set, in `rids` order.
 
@@ -931,6 +946,7 @@ class PagedCacheManager:
             cache["kv_pos"] = jnp.stack(rows, axis=0)
         return cache
 
+    @spanned("pages.absorb")
     def absorb(self, rids: list[Any], new_cache: dict, *,
                advance: int = 1) -> None:
         """Store one decode step's outputs back: pools are shared (one

@@ -28,6 +28,7 @@ from repro.distributed.fault import Watchdog
 from repro.memo.table import MemoTable
 from repro.monitor.examon import ExamonBroker, get_default_broker
 from repro.monitor.sensors import apply_wrappers
+from repro.monitor.spans import SpanRecorder
 from repro.nn.module import init_params
 from repro.runtime.pages import (
     PagedCacheManager,
@@ -43,6 +44,35 @@ from repro.runtime.steps import (
     stack_request_caches,
 )
 from repro.versioning.libvc import LibVC
+
+# the phases of a `wave` event's `host_ms`: the host spans of the serving
+# loop and the page pool, each by its self time, and `consumer`, the time
+# serve_stream sat suspended at its yields while its caller worked
+WAVE_PHASES = (
+    "serve.wave",           # the loop's own work outside every span below
+    "serve.admit",          # one admission (rid), one-shot prefill included
+    "serve.retire",         # one retirement (rid)
+    "serve.prefill_chunk",  # one chunk of a chunked admission (rid)
+    "serve.dispatch",       # a jitted step's inputs and call, until it returns
+    "serve.wait",           # a blocking read-back of a step's results
+    "serve.emit",           # the step's tokens into outputs and events
+    "serve.qos",            # the QoS governor's decide and observe
+    "pages.compose",        # PagedCacheManager.batch: grow, CoW, compose
+    "pages.absorb",         # PagedCacheManager.absorb
+    "pages.prefill_view",   # a prefill or re-score view of the pool
+    "pages.absorb_prefill",  # a prefill chunk's pool writes
+    "pages.admit",          # admission bookkeeping (can_admit, admit_*)
+    "pages.release",        # retire / abort: pages back to the pool
+    "consumer",
+)
+
+
+def _host_ms(self_s: dict[str, float]) -> dict[str, float]:
+    """Milliseconds of self time per phase, every documented phase."""
+    out = {p: 0.0 for p in WAVE_PHASES}
+    for name, secs in self_s.items():
+        out[name] = out.get(name, 0.0) + 1e3 * secs
+    return out
 
 
 @dataclasses.dataclass
@@ -168,6 +198,7 @@ class Server:
         self.last_outcomes: list[dict[str, Any]] | None = None  # per request
         self.last_qos_stats: dict[str, Any] | None = None  # QoS governor
         self._last_admit_rescored = False  # last admission was a re-score
+        self._last_admit_computed = 0  # prompt tokens its prefill computed
         self._verify_steps: dict[tuple, Callable] = {}  # (variant, S) -> fn
 
     def _variant(self) -> str | None:
@@ -334,18 +365,12 @@ class Server:
         NaN/Inf admission rolls its partial pool state back and raises
         `NonFiniteLogits` for the caller's structured-rejection path.
         """
+        rec = manager.spans
         toks = jnp.asarray(prompt, jnp.int32).reshape(1, -1)
         toks_np = np.asarray(prompt, np.int64).reshape(-1)
         S = int(toks.shape[1])
         if not manager.has_structure:
-            _, probe = self.probe_vc(variant, self.params,
-                                     {"tokens": toks[:, :1]})
-            if not paged_compatible(probe):
-                raise ValueError(
-                    "model cache is not paged-compatible (SSM/recurrent "
-                    "state) — use serve_batch")
-            ring = manager.window is not None and manager.window < S
-            manager.init_structure(probe, ring=ring)
+            self._init_pool_structure(manager, toks, variant)
         shared_pages, shared_len = manager.match_prefix(toks_np)
         if shared_len >= S:
             # Long prompts compute their unshared first token through the
@@ -366,6 +391,7 @@ class Server:
                     shared_pages = shared_pages[:-1]
                     shared_len -= ps
         self._last_admit_rescored = shared_len >= S
+        self._last_admit_computed = 0 if shared_len >= S else S - shared_len
         # "paged_prefill" join point fires before any pool allocation, so a
         # raise-kind fault leaves nothing to roll back (the caller's abort
         # is then a no-op); a nan-kind poisons the logits below, driving
@@ -375,31 +401,58 @@ class Server:
             manager.admit_shared(rid, toks_np, final_len=final_len,
                                  pages=shared_pages)
             view = manager.rescore_view(rid)
-            logits, _ = self.rescore_vc(
-                variant, self.params,
-                {"tokens": toks[:, -1:],
-                 "positions": jnp.full((1, 1), S - 1, jnp.int32)},
-                view,
-            )
+            with rec.span("serve.dispatch"):
+                logits, _ = self.rescore_vc(
+                    variant, self.params,
+                    {"tokens": toks[:, -1:],
+                     "positions": jnp.full((1, 1), S - 1, jnp.int32)},
+                    view,
+                )
         else:
             view, start = manager.admit_begin(
                 rid, toks_np, final_len=final_len,
                 shared_pages=shared_pages, shared_len=shared_len)
-            pos = jnp.arange(start, S, dtype=jnp.int32)[None]
-            logits, new_cache = self.paged_prefill_vc(
-                variant, self.params,
-                {"tokens": toks[:, start:], "positions": pos},
-                view, prefix_len=start,
-            )
+            with rec.span("serve.dispatch"):
+                pos = jnp.arange(start, S, dtype=jnp.int32)[None]
+                logits, new_cache = self.paged_prefill_vc(
+                    variant, self.params,
+                    {"tokens": toks[:, start:], "positions": pos},
+                    view, prefix_len=start,
+                )
             manager.admit_finish(rid, new_cache, toks_np)
         if spec is not None and spec.kind == "nan_logits":
             logits = jnp.full_like(logits, jnp.nan)
-        if not bool(np.isfinite(float(
-                jnp.max(logits[0, -1].astype(jnp.float32))))):
+        return self._first_of(manager, rid, logits), spec
+
+    def _init_pool_structure(self, manager: PagedCacheManager, toks,
+                             variant) -> None:
+        """Learn the pool's groups and dtypes from a 1-token probe
+        prefill (the first admission of a serve)."""
+        with manager.spans.span("serve.dispatch"):
+            _, probe = self.probe_vc(variant, self.params,
+                                     {"tokens": toks[:, :1]})
+        if not paged_compatible(probe):
+            raise ValueError(
+                "model cache is not paged-compatible (SSM/recurrent "
+                "state) — use serve_batch")
+        ring = manager.window is not None and manager.window < toks.shape[1]
+        manager.init_structure(probe, ring=ring)
+
+    @staticmethod
+    def _first_of(manager: PagedCacheManager, rid, logits) -> int:
+        """The first output token of an admission, read back from its
+        prefill logits; non-finite logits roll the admission back and
+        raise `NonFiniteLogits`."""
+        with manager.spans.span("serve.wait"):
+            last = logits[0, -1]
+            finite = bool(np.isfinite(float(
+                jnp.max(last.astype(jnp.float32)))))
+            tok = int(jnp.argmax(last, axis=-1)) if finite else None
+        if not finite:
             manager.abort(rid)
             raise NonFiniteLogits(
                 f"non-finite prefill logits for request {rid!r}")
-        return int(jnp.argmax(logits[0, -1], axis=-1)), spec
+        return tok
 
     def _admit_grouped(self, manager: PagedCacheManager, rid, prompt,
                        final_len: int, first_tok: int) -> int | None:
@@ -513,14 +566,7 @@ class Server:
         toks_np = np.asarray(prompt, np.int64).reshape(-1)
         S = int(toks.shape[1])
         if not manager.has_structure:
-            _, probe = self.probe_vc(variant, self.params,
-                                     {"tokens": toks[:, :1]})
-            if not paged_compatible(probe):
-                raise ValueError(
-                    "model cache is not paged-compatible (SSM/recurrent "
-                    "state) — use serve_batch")
-            ring = manager.window is not None and manager.window < S
-            manager.init_structure(probe, ring=ring)
+            self._init_pool_structure(manager, toks, variant)
         state_extra = self.woven.variant_state(
             None if variant in (None, "__default__") else variant
         ).extra
@@ -543,29 +589,25 @@ class Server:
         def cont() -> dict:
             done = st["done"]
             end = min(done + step, S)
-            pos = jnp.arange(done, end, dtype=jnp.int32)[None]
             # the view is rebuilt per chunk, never cached: the dispatches
             # interleaved between chunks (decode steps, other admissions)
             # donate the pool arrays, so a held view would reference
             # deleted buffers — prefill_view rebinds to the live pools
-            logits, new_cache = self.paged_prefill_vc(
-                variant, self.params,
-                {"tokens": toks[:, done:end], "positions": pos},
-                manager.prefill_view(rid, done), prefix_len=done)
+            view = manager.prefill_view(rid, done)
+            with manager.spans.span("serve.dispatch"):
+                pos = jnp.arange(done, end, dtype=jnp.int32)[None]
+                logits, new_cache = self.paged_prefill_vc(
+                    variant, self.params,
+                    {"tokens": toks[:, done:end], "positions": pos},
+                    view, prefix_len=done)
             if end < S:
                 manager.absorb_prefill(rid, new_cache)
                 st["done"] = end
                 return {"tok": None, "resident": end, "chunk": end - done}
             manager.admit_finish(rid, new_cache, toks_np)
-            lg = logits
             if spec is not None and spec.kind == "nan_logits":
-                lg = jnp.full_like(lg, jnp.nan)
-            if not bool(np.isfinite(float(
-                    jnp.max(lg[0, -1].astype(jnp.float32))))):
-                manager.abort(rid)
-                raise NonFiniteLogits(
-                    f"non-finite prefill logits for request {rid!r}")
-            return {"tok": int(jnp.argmax(lg[0, -1], axis=-1)),
+                logits = jnp.full_like(logits, jnp.nan)
+            return {"tok": self._first_of(manager, rid, logits),
                     "resident": S, "chunk": end - done}
 
         return None, spec, cont
@@ -783,7 +825,17 @@ class Server:
           {"event": "token",  "rid": r, "token": t, "index": i}
           {"event": "outcome","rid": r, "status": s, "reason": ..., "tokens": n}
           {"event": "wave",   "batch": B, "dt_s": dt, "emitted": e,
-           "prefill_tokens": p, "k": k_eff, "op": knobs-or-None}
+           "prefill_tokens": p, "k": k_eff, "op": knobs-or-None,
+           "host_ms": {phase: ms}}
+
+        A wave that decodes nothing emits no "wave" event; its work carries
+        into the next one.  So each "wave" event covers the waves since the
+        previous one: `dt_s` is their time inside the loop, `host_ms` that
+        time split by phase (`WAVE_PHASES`, self times of the host spans
+        of `repro.monitor.spans`, plus `consumer`, the caller's time
+        between them), `emitted` and `prefill_tokens` the tokens emitted
+        and the prompt tokens prefills computed.  The same spans appear
+        on a profiler trace's host plane when one is taken.
 
         Chunked prefill (`prefill_chunk` > 0, ServerConfig, or the QoS
         governor's knob): a long admission reserves its block table up
@@ -864,10 +916,13 @@ class Server:
             # the donor still maps.  Prefix sharing stays off; the
             # direct-to-pool paged prefill still applies.
             share = False
+        # one recorder times the loop, both pools and the steps (WAVE_PHASES)
+        rec = SpanRecorder()
         manager = PagedCacheManager(
             pool_pages, ps, max_len=self.cfg.max_cache_len,
             window=getattr(self.woven.program.cfg, "attn_window", None),
             prefix_sharing=share, cache_dtype=cache_dtype, device=self.device,
+            spans=rec,
         )
         # feedback observations are per-knob-setting: start a fresh window,
         # bucketed by batch size (a decode step's cost scales with the live
@@ -895,7 +950,7 @@ class Server:
                 window=getattr(draft_srv.woven.program.cfg,
                                "attn_window", None),
                 prefix_sharing=False, cache_dtype=cache_dtype,
-                device=draft_srv.device,
+                device=draft_srv.device, spans=rec,
             )
 
         # logical-clock arrivals: requests with a future arrival wave sit
@@ -938,6 +993,7 @@ class Server:
         # regardless of when the consumer drains
         evq: list[dict] = []
         wave = 0
+        # tokens emitted and prefilled since the last wave event
         wavestat = {"emitted": 0, "prefill_tokens": 0}
         now0 = time.perf_counter()
         rq: dict[int, dict] = {
@@ -945,21 +1001,22 @@ class Server:
                 "first_wave": None, "tok_t": []}
             for r in range(len(prompts))}
 
-        def _emit(kind: str, **kw) -> None:
-            evq.append({"event": kind, "wave": wave,
-                        "t": time.perf_counter(), **kw})
+        def _emit(kind: str, **kw) -> dict:
+            ev = {"event": kind, "wave": wave, "t": time.perf_counter(), **kw}
+            evq.append(ev)
+            return ev
 
         def _first_token(rid, tok) -> None:
             outputs[rid] = [tok]
             active[rid] = {"tok": tok, "pos": lengths[rid]}
+            wavestat["emitted"] += 1
             m = rq[rid]
-            m["first_t"] = time.perf_counter()
+            m["first_t"] = _emit("token", rid=rid, token=tok, index=0)["t"]
             m["first_wave"] = wave
             m["tok_t"].append(m["first_t"])
-            wavestat["emitted"] += 1
-            _emit("token", rid=rid, token=tok, index=0)
             if gov is not None:
-                gov.observe("ttft_s", m["first_t"] - m["arrive_t"])
+                with rec.span("serve.qos"):
+                    gov.observe("ttft_s", m["first_t"] - m["arrive_t"])
 
         # -- resilience machinery ---------------------------------------------
         # every fault the policy can absorb lands in `outcome` / `actions`
@@ -1085,18 +1142,20 @@ class Server:
                     dmanager.abort(r)
 
         def _retire(rid):
-            try:
-                _retry("retire", lambda: (_fire("retire", rid=rid),
-                                          manager.retire(rid)))
-            except _StepAbort as e:
-                # a retire that keeps failing force-drops the references —
-                # leaking pages on a fault path would starve later
-                # admissions
-                manager.abort(rid)
-                actions.append({"point": "retire", "kind": "forced_abort",
-                                "rid": rid, "error": str(e.cause)})
-            if dmanager is not None:
-                dmanager.abort(rid)
+            with rec.span("serve.retire", rid=rid):
+                try:
+                    _retry("retire", lambda: (_fire("retire", rid=rid),
+                                              manager.retire(rid)))
+                except _StepAbort as e:
+                    # a retire that keeps failing force-drops the
+                    # references — leaking pages on a fault path would
+                    # starve later admissions
+                    manager.abort(rid)
+                    actions.append({"point": "retire",
+                                    "kind": "forced_abort", "rid": rid,
+                                    "error": str(e.cause)})
+                if dmanager is not None:
+                    dmanager.abort(rid)
 
         def admit_one(rid, reuse_from=None) -> None:
             aspec = _fire("admit", rid=rid)
@@ -1127,12 +1186,13 @@ class Server:
                 if pspec is not None and pspec.kind == "deadline":
                     forced_deadline.add(rid)
                 if cont is None:
-                    # a one-shot admission processed the whole prompt this
-                    # wave — billed into the wave event like a chunk is, so
-                    # the governor's tok_s observations (and any modeled
-                    # clock over the event stream) see admission stalls on
-                    # both prefill paths
-                    wavestat["prefill_tokens"] += lengths[rid]
+                    # a one-shot admission prefilled its prompt this wave —
+                    # billed into the wave event like a chunk is, by the
+                    # tokens it computed (a shared prefix is not), so the
+                    # governor's tok_s observations (and any modeled clock
+                    # over the event stream) see admission stalls on both
+                    # prefill paths
+                    wavestat["prefill_tokens"] += self._last_admit_computed
             start_t[rid] = time.monotonic()
             if cont is not None:
                 # chunked admission in flight: the block table is
@@ -1160,18 +1220,20 @@ class Server:
                     _degrade(f"draft admission failed: {e}")
 
         def try_admit(rid, reuse_from=None) -> bool:
-            try:
-                admit_one(rid, reuse_from)
-                return True
-            except (FaultError, PoolExhausted) as e:
-                # a failed admission is isolated to the one request: its
-                # partial pool state rolls back and it gets a structured
-                # rejection — the serve (and every other request) goes on
-                outputs.pop(rid, None)
-                _drop(rid)
-                _reject(rid, str(e))
-                _audit()
-                return False
+            with rec.span("serve.admit", rid=rid):
+                try:
+                    admit_one(rid, reuse_from)
+                    return True
+                except (FaultError, PoolExhausted) as e:
+                    # a failed admission is isolated to the one request:
+                    # its partial pool state rolls back and it gets a
+                    # structured rejection — the serve (and every other
+                    # request) goes on
+                    outputs.pop(rid, None)
+                    _drop(rid)
+                    _reject(rid, str(e))
+                    _audit()
+                    return False
 
         def admit_ready() -> None:
             # the admission gate counts chunked prefills in flight: they
@@ -1259,361 +1321,380 @@ class Server:
         aborted: Exception | None = None
         while active or waiting or prefilling or pending:
             while evq:
-                yield evq.pop(0)
-            t_wave = time.perf_counter()
-            wavestat["emitted"] = 0
-            wavestat["prefill_tokens"] = 0
-            # logical-clock arrivals land before anything else this wave
-            if pending:
-                arrived = False
-                while pending and arrive_at[pending[0]] <= wave:
-                    r = pending.popleft()
-                    waiting.append(r)
-                    rq[r]["arrive_t"] = time.perf_counter()
-                    rq[r]["arrive_wave"] = wave
-                    arrived = True
-                if arrived:
+                ev = evq.pop(0)
+                with rec.outside("consumer"):
+                    yield ev
+            with rec.span("serve.wave", wave=wave) as wave_span:
+                # logical-clock arrivals land before anything else this wave
+                if pending:
+                    arrived = False
+                    while pending and arrive_at[pending[0]] <= wave:
+                        r = pending.popleft()
+                        waiting.append(r)
+                        rq[r]["arrive_t"] = time.perf_counter()
+                        rq[r]["arrive_wave"] = wave
+                        arrived = True
+                    if arrived:
+                        _admit_or_drain()
+                # the governor re-selects the serving operating point as load
+                # shifts (every reselect_every waves); knob moves only change
+                # scheduling — every emitted token stays a target argmax
+                if gov is not None and wave % gov.reselect_every == 0:
+                    with rec.span("serve.qos"):
+                        op = gov.decide(wave=wave,
+                                        waiting=len(waiting) + len(pending),
+                                        active=len(active) + len(prefilling))
+                    v = op.get("max_batch")
+                    knobs["max_batch"] = min(max_batch, int(v)) if v \
+                        else max_batch
+                    if chunk_ok and chunk_cfg is None \
+                            and op.get("prefill_chunk") is not None:
+                        knobs["chunk"] = int(op["prefill_chunk"])
+                    if op.get("draft_len") is not None:
+                        knobs["k"] = min(k_max, int(op["draft_len"]))
+                    if op.get("freq") is not None:
+                        knobs["freq"] = float(op["freq"])
+                if wave == 0:
                     _admit_or_drain()
-            # the governor re-selects the serving operating point as load
-            # shifts (every reselect_every waves); knob moves only change
-            # scheduling — every emitted token stays a target argmax
-            if gov is not None and wave % gov.reselect_every == 0:
-                op = gov.decide(wave=wave,
-                                waiting=len(waiting) + len(pending),
-                                active=len(active) + len(prefilling))
-                v = op.get("max_batch")
-                knobs["max_batch"] = min(max_batch, int(v)) if v \
-                    else max_batch
-                if chunk_ok and chunk_cfg is None \
-                        and op.get("prefill_chunk") is not None:
-                    knobs["chunk"] = int(op["prefill_chunk"])
-                if op.get("draft_len") is not None:
-                    knobs["k"] = min(k_max, int(op["draft_len"]))
-                if op.get("freq") is not None:
-                    knobs["freq"] = float(op["freq"])
-            if wave == 0:
-                _admit_or_drain()
-            # preemption arriving mid-wave drains the queue at the next
-            # round boundary; the admitted batch keeps decoding to the end
-            if preemption is not None and preemption.pending \
-                    and (waiting or pending):
-                _drain_waiting()
-                if not active and not prefilling:
-                    break
-            # retire before stepping: requests at their budget free pages
-            done = [r for r in active if len(outputs[r]) >= n]
-            for rid in done:
-                _retire(rid)
-                del active[rid]
-                _emit("outcome", rid=rid, status=outcome[rid]["status"],
-                      reason=outcome[rid]["reason"],
-                      tokens=len(outputs[rid][:n]))
-            # per-request SLO sweep: overdue requests (wall clock past
-            # deadline_s, or forced over by an injected `deadline` fault)
-            # retire with partial output and a deadline_exceeded marker —
-            # chunked admissions still prefilling are swept too (their
-            # clock started at reservation)
-            overdue = []
-            if deadline_s_eff is not None or forced_deadline:
-                now = time.monotonic()
-                overdue = [r for r in list(active) + list(prefilling)
-                           if r in forced_deadline
-                           or (deadline_s_eff is not None
-                               and now - start_t[r] > deadline_s_eff)]
-            for rid in overdue:
-                outcome[rid] = {"status": "deadline_exceeded",
-                                "reason": "request exceeded its deadline"}
-                fstats["deadline_exceeded"] += 1
-                actions.append({"point": "decode_step", "kind": "deadline",
-                                "rid": rid,
-                                "emitted": len(outputs.get(rid, []))})
-                _emit("outcome", rid=rid, status="deadline_exceeded",
-                      reason=outcome[rid]["reason"],
-                      tokens=len(outputs.get(rid, [])))
-                if rid in prefilling:
-                    # mid-prefill: nothing registered yet — abort the
-                    # reserved pages instead of retiring
-                    del prefilling[rid]
-                    _drop(rid)
-                else:
+                # preemption arriving mid-wave drains the queue at the next
+                # round boundary; the admitted batch keeps decoding to the end
+                if preemption is not None and preemption.pending \
+                        and (waiting or pending):
+                    _drain_waiting()
+                    if not active and not prefilling:
+                        break
+                # retire before stepping: requests at their budget free pages
+                done = [r for r in active if len(outputs[r]) >= n]
+                for rid in done:
                     _retire(rid)
-                    active.pop(rid, None)
-                forced_deadline.discard(rid)
-            if done or overdue:
-                _audit()
-                _admit_or_drain()
-            # advance chunked prefills: one page-aligned chunk per request
-            # per wave — in-flight decodes below never wait on a long
-            # admission, the newcomer streams in beside them
-            for rid in list(prefilling):
-                try:
-                    step_r = prefilling[rid]()
-                except (FaultError, PoolExhausted) as e:
-                    outputs.pop(rid, None)
-                    _drop(rid)
-                    _reject(rid, str(e))
+                    del active[rid]
+                    _emit("outcome", rid=rid, status=outcome[rid]["status"],
+                          reason=outcome[rid]["reason"],
+                          tokens=len(outputs[rid][:n]))
+                # per-request SLO sweep: overdue requests (wall clock past
+                # deadline_s, or forced over by an injected `deadline` fault)
+                # retire with partial output and a deadline_exceeded marker —
+                # chunked admissions still prefilling are swept too (their
+                # clock started at reservation)
+                overdue = []
+                if deadline_s_eff is not None or forced_deadline:
+                    now = time.monotonic()
+                    overdue = [r for r in list(active) + list(prefilling)
+                               if r in forced_deadline
+                               or (deadline_s_eff is not None
+                                   and now - start_t[r] > deadline_s_eff)]
+                for rid in overdue:
+                    outcome[rid] = {"status": "deadline_exceeded",
+                                    "reason": "request exceeded its deadline"}
+                    fstats["deadline_exceeded"] += 1
+                    actions.append({"point": "decode_step", "kind": "deadline",
+                                    "rid": rid,
+                                    "emitted": len(outputs.get(rid, []))})
+                    _emit("outcome", rid=rid, status="deadline_exceeded",
+                          reason=outcome[rid]["reason"],
+                          tokens=len(outputs.get(rid, [])))
+                    if rid in prefilling:
+                        # mid-prefill: nothing registered yet — abort the
+                        # reserved pages instead of retiring
+                        del prefilling[rid]
+                        _drop(rid)
+                    else:
+                        _retire(rid)
+                        active.pop(rid, None)
+                    forced_deadline.discard(rid)
+                if done or overdue:
                     _audit()
-                    continue
-                wavestat["prefill_tokens"] += step_r["chunk"]
-                if step_r["tok"] is None:
-                    _emit("prefill_chunk", rid=rid,
-                          resident=step_r["resident"], total=lengths[rid])
-                else:
-                    del prefilling[rid]
-                    _first_token(rid, step_r["tok"])
-            if not active:
-                if waiting and not prefilling:
-                    # pool at its emptiest still can't fit the head
-                    # request: reject *it* and keep serving the rest — the
-                    # old batch-killing RuntimeError here threw away every
-                    # completed request's output with it
-                    rid = waiting.popleft()
-                    _reject(rid, f"page pool too small: request {rid} "
-                                 f"needs more pages than the pool holds")
                     _admit_or_drain()
-                    wave += 1
-                    continue
-                if prefilling or pending:
-                    # nothing to decode this wave: prefill chunks advanced
-                    # above / the clock ticks toward the next arrival
-                    wave += 1
-                    continue
-                break
-
-            rids = list(active)
-            # a verify round writes k_eff+1 slots per request; past the
-            # final_len clamp (cache capacity) fall back to plain rounds —
-            # S stays within the knob grid so only a few step shapes ever
-            # compile (static serves keep the old {1, k+1} pair)
-            k_eff = int(knobs["k"]) if spec["on"] else 0
-            S = k_eff + 1 if (k_eff and spec["on"] and all(
-                active[r]["pos"] + k_eff + 1 <= finals[r] for r in rids)) \
-                else 1
-
-            if S > 1 and dmanager is not None:
-                # dynamic draft_len: plain waves (k_eff == 0 under the
-                # governor) leave the draft cache behind the target's
-                # accepted length — replay the emitted tokens through the
-                # draft before the round so the lockstep invariant holds.
-                # Static-k serves never enter the replay loop.
-                try:
-                    self._draft_sync(draft_srv, dmanager, rids, active,
-                                     outputs, lengths)
-                except Exception as e:
-                    _degrade(f"draft catch-up fault: {e}")
-                    S = 1
-
-            if S > 1:
-                pos0 = {r: active[r]["pos"] for r in rids}
-                fed = np.zeros((len(rids), S), np.int64)
-                fed[:, 0] = [active[r]["tok"] for r in rids]
-                # draft proposes k greedy tokens; the final iteration is a
-                # write-only catch-up (its KV for slot pos+k is needed when
-                # every draft token is accepted), its proposal is unused
-                try:
-                    for s in range(S):
-                        dspec = _fire("draft_step", rids=rids)
-                        dcache = dmanager.batch(rids)
-                        dpos = jnp.asarray([[pos0[r] + s] for r in rids],
-                                           jnp.int32)
-                        dlogits, dnew = draft_srv.decode_vc(
-                            None, draft_srv.params,
-                            {"tokens": jnp.asarray(fed[:, s:s + 1],
-                                                   jnp.int32),
-                             "positions": dpos},
-                            dcache)
-                        if dspec is not None \
-                                and dspec.kind == "nan_logits":
-                            # a poisoned proposal is still a legal token
-                            # after argmax (NaN rows argmax to 0): the
-                            # verify step rejects garbage proposals, so a
-                            # bad draft costs steps, never correctness
-                            vi = rids.index(dspec.rid) \
-                                if dspec.rid in rids else 0
-                            dlogits = dlogits.at[vi].set(jnp.nan)
-                        dmanager.absorb(rids, dnew)
-                        stats["draft_steps"] += 1
-                        if s < S - 1:
-                            fed[:, s + 1] = np.asarray(
-                                jnp.argmax(dlogits[:, -1], axis=-1),
-                                np.int64)
-                except Exception as e:
-                    # draft-side fault: no target state was touched this
-                    # round — degrade to plain decode and re-run the round
-                    _degrade(f"draft fault: {e}")
-                    wave += 1
-                    continue
-
-                # ONE widened-q target step scores all S draft positions
-                def _verify_round():
-                    _fire("cow", rids=rids)
-                    cache = manager.batch(rids, tokens=S)
-                    vspec = _fire("verify_step", rids=rids)
-                    vpos = jnp.asarray(
-                        [[pos0[r] + s for s in range(S)] for r in rids],
-                        jnp.int32)
-                    ts = time.perf_counter()
-                    if watchdog is not None:
-                        watchdog.beat()
-                    logits, new_cache = self._verify_step(variant, k_eff)(
-                        self.params,
-                        {"tokens": jnp.asarray(fed, jnp.int32),
-                         "positions": vpos},
-                        cache)
-                    if watchdog is not None:
-                        watchdog.cancel()
-                    return vspec, ts, logits, new_cache
-
-                try:
-                    vspec, ts, logits, new_cache = _retry("verify_step",
-                                                          _verify_round)
-                except _StepAbort as err:
-                    aborted = err
-                    break
-                if vspec is not None and vspec.kind == "nan_logits":
-                    vi = rids.index(vspec.rid) if vspec.rid in rids else 0
-                    logits = logits.at[vi].set(jnp.nan)
-                finite = np.asarray(jnp.isfinite(jnp.max(
-                    logits.astype(jnp.float32), axis=(-2, -1))))
-                targ = np.asarray(jnp.argmax(logits, axis=-1), np.int64)
-                if stats["verify_steps"]:  # skip the jit-tracing first step
-                    verify_lats.append(time.perf_counter() - ts)
-                manager.absorb(rids, new_cache, advance=S)
-                stats["verify_steps"] += 1
-                stats["rounds"] += 1
-                stats["request_rounds"] += len(rids)
-                accepted_round = 0
-                rolled = False
-                for i, rid in enumerate(rids):
-                    if not finite[i]:
-                        _quarantine(rid, "non-finite verify logits")
-                        rolled = True
+                # advance chunked prefills: one page-aligned chunk per request
+                # per wave — in-flight decodes below never wait on a long
+                # admission, the newcomer streams in beside them
+                for rid in list(prefilling):
+                    with rec.span("serve.prefill_chunk", rid=rid):
+                        try:
+                            step_r = prefilling[rid]()
+                        except (FaultError, PoolExhausted) as e:
+                            outputs.pop(rid, None)
+                            _drop(rid)
+                            _reject(rid, str(e))
+                            _audit()
+                            continue
+                        wavestat["prefill_tokens"] += step_r["chunk"]
+                        if step_r["tok"] is None:
+                            _emit("prefill_chunk", rid=rid,
+                                  resident=step_r["resident"],
+                                  total=lengths[rid])
+                        else:
+                            del prefilling[rid]
+                            _first_token(rid, step_r["tok"])
+                if not active:
+                    if waiting and not prefilling:
+                        # pool at its emptiest still can't fit the head
+                        # request: reject *it* and keep serving the rest — the
+                        # old batch-killing RuntimeError here threw away every
+                        # completed request's output with it
+                        rid = waiting.popleft()
+                        _reject(rid, f"page pool too small: request {rid} "
+                                     f"needs more pages than the pool holds")
+                        _admit_or_drain()
+                        wave += 1
                         continue
-                    # accept the longest draft prefix matching the
-                    # target's own argmax chain, plus the correction
-                    # token — every emitted token is a target argmax,
-                    # so greedy output is bit-identical to plain decode
-                    a = 0
-                    while a < k_eff and fed[i, a + 1] == targ[i, a]:
-                        a += 1
-                    e = min(a + 1, n - len(outputs[rid]))
-                    idx0 = len(outputs[rid])
-                    outputs[rid].extend(int(t) for t in targ[i, :e])
-                    new_len = pos0[rid] + e
-                    # rejected tail: O(1) refcount rollback, no page copies
+                    if prefilling or pending:
+                        # nothing to decode this wave: prefill chunks advanced
+                        # above / the clock ticks toward the next arrival
+                        wave += 1
+                        continue
+                    break
+
+                rids = list(active)
+                # a verify round writes k_eff+1 slots per request; past the
+                # final_len clamp (cache capacity) fall back to plain rounds —
+                # S stays within the knob grid so only a few step shapes ever
+                # compile (static serves keep the old {1, k+1} pair)
+                k_eff = int(knobs["k"]) if spec["on"] else 0
+                S = k_eff + 1 if (k_eff and spec["on"] and all(
+                    active[r]["pos"] + k_eff + 1 <= finals[r] for r in rids)) \
+                    else 1
+
+                if S > 1 and dmanager is not None:
+                    # dynamic draft_len: plain waves (k_eff == 0 under the
+                    # governor) leave the draft cache behind the target's
+                    # accepted length — replay the emitted tokens through the
+                    # draft before the round so the lockstep invariant holds.
+                    # Static-k serves never enter the replay loop.
                     try:
-                        _retry("rollback", lambda rid=rid, nl=new_len: (
-                            _fire("rollback", rid=rid),
-                            manager.rollback(rid, nl),
-                            dmanager.rollback(rid, nl)))
+                        self._draft_sync(draft_srv, dmanager, rids, active,
+                                         outputs, lengths)
+                    except Exception as e:
+                        _degrade(f"draft catch-up fault: {e}")
+                        S = 1
+
+                if S > 1:
+                    pos0 = {r: active[r]["pos"] for r in rids}
+                    fed = np.zeros((len(rids), S), np.int64)
+                    fed[:, 0] = [active[r]["tok"] for r in rids]
+                    # draft proposes k greedy tokens; the final iteration is a
+                    # write-only catch-up (its KV for slot pos+k is needed when
+                    # every draft token is accepted), its proposal is unused
+                    try:
+                        for s in range(S):
+                            dspec = _fire("draft_step", rids=rids)
+                            dcache = dmanager.batch(rids)
+                            with rec.span("serve.dispatch"):
+                                dpos = jnp.asarray(
+                                    [[pos0[r] + s] for r in rids], jnp.int32)
+                                dlogits, dnew = draft_srv.decode_vc(
+                                    None, draft_srv.params,
+                                    {"tokens": jnp.asarray(fed[:, s:s + 1],
+                                                           jnp.int32),
+                                     "positions": dpos},
+                                    dcache)
+                            if dspec is not None \
+                                    and dspec.kind == "nan_logits":
+                                # a poisoned proposal is still a legal token
+                                # after argmax (NaN rows argmax to 0): the
+                                # verify step rejects garbage proposals, so a
+                                # bad draft costs steps, never correctness
+                                vi = rids.index(dspec.rid) \
+                                    if dspec.rid in rids else 0
+                                dlogits = dlogits.at[vi].set(jnp.nan)
+                            dmanager.absorb(rids, dnew)
+                            stats["draft_steps"] += 1
+                            if s < S - 1:
+                                with rec.span("serve.wait"):
+                                    fed[:, s + 1] = np.asarray(
+                                        jnp.argmax(dlogits[:, -1], axis=-1),
+                                        np.int64)
+                    except Exception as e:
+                        # draft-side fault: no target state was touched this
+                        # round — degrade to plain decode and re-run the round
+                        _degrade(f"draft fault: {e}")
+                        wave += 1
+                        continue
+
+                    # ONE widened-q target step scores all S draft positions
+                    def _verify_round():
+                        _fire("cow", rids=rids)
+                        cache = manager.batch(rids, tokens=S)
+                        vspec = _fire("verify_step", rids=rids)
+                        with rec.span("serve.dispatch") as sent:
+                            vpos = jnp.asarray(
+                                [[pos0[r] + s for s in range(S)]
+                                 for r in rids], jnp.int32)
+                            if watchdog is not None:
+                                watchdog.beat()
+                            logits, new_cache = self._verify_step(
+                                variant, k_eff)(
+                                self.params,
+                                {"tokens": jnp.asarray(fed, jnp.int32),
+                                 "positions": vpos},
+                                cache)
+                            if watchdog is not None:
+                                watchdog.cancel()
+                        return vspec, sent, logits, new_cache
+
+                    try:
+                        vspec, sent, logits, new_cache = _retry(
+                            "verify_step", _verify_round)
                     except _StepAbort as err:
-                        # a rollback that keeps failing leaves the
-                        # request's length unknown: quarantine it
-                        _quarantine(rid, f"rollback failed: {err.cause}")
-                        rolled = True
-                        continue
-                    active[rid]["tok"] = int(targ[i, e - 1])
-                    active[rid]["pos"] = new_len
-                    t_tok = time.perf_counter()
-                    for j in range(e):
-                        rq[rid]["tok_t"].append(t_tok)
-                        _emit("token", rid=rid, token=int(targ[i, j]),
-                              index=idx0 + j)
-                    wavestat["emitted"] += e
-                    stats["proposed"] += k_eff
-                    stats["accepted"] += a
-                    stats["emitted_spec"] += e
-                    accepted_round += a
-                    rolled = True
-                if rolled:
-                    _audit()
-                if accepted_round == 0:
-                    mismatch_rounds += 1
-                    patience = res["spec_patience"]
-                    if patience is not None \
-                            and mismatch_rounds >= int(patience):
-                        _degrade(f"{mismatch_rounds} consecutive "
-                                 f"all-reject verify rounds")
+                        aborted = err
+                        break
+                    if vspec is not None and vspec.kind == "nan_logits":
+                        vi = rids.index(vspec.rid) if vspec.rid in rids else 0
+                        logits = logits.at[vi].set(jnp.nan)
+                    with rec.span("serve.wait") as waited:
+                        finite = np.asarray(jnp.isfinite(jnp.max(
+                            logits.astype(jnp.float32), axis=(-2, -1))))
+                        targ = np.asarray(jnp.argmax(logits, axis=-1),
+                                          np.int64)
+                    if stats["verify_steps"]:  # skip the jit-tracing first step
+                        verify_lats.append(sent.seconds + waited.seconds)
+                    manager.absorb(rids, new_cache, advance=S)
+                    stats["verify_steps"] += 1
+                    stats["rounds"] += 1
+                    stats["request_rounds"] += len(rids)
+                    accepted_round = 0
+                    rolled = False
+                    with rec.span("serve.emit"):
+                        for i, rid in enumerate(rids):
+                            if not finite[i]:
+                                _quarantine(rid, "non-finite verify logits")
+                                rolled = True
+                                continue
+                            # accept the longest draft prefix matching the
+                            # target's own argmax chain, plus the correction
+                            # token — every emitted token is a target argmax,
+                            # so greedy output is bit-identical to plain decode
+                            a = 0
+                            while a < k_eff and fed[i, a + 1] == targ[i, a]:
+                                a += 1
+                            e = min(a + 1, n - len(outputs[rid]))
+                            idx0 = len(outputs[rid])
+                            outputs[rid].extend(int(t) for t in targ[i, :e])
+                            new_len = pos0[rid] + e
+                            # rejected tail: O(1) refcount rollback, no page copies
+                            try:
+                                _retry("rollback", lambda rid=rid, nl=new_len: (
+                                    _fire("rollback", rid=rid),
+                                    manager.rollback(rid, nl),
+                                    dmanager.rollback(rid, nl)))
+                            except _StepAbort as err:
+                                # a rollback that keeps failing leaves the
+                                # request's length unknown: quarantine it
+                                _quarantine(rid, f"rollback failed: {err.cause}")
+                                rolled = True
+                                continue
+                            active[rid]["tok"] = int(targ[i, e - 1])
+                            active[rid]["pos"] = new_len
+                            for j in range(e):
+                                rq[rid]["tok_t"].append(_emit(
+                                    "token", rid=rid, token=int(targ[i, j]),
+                                    index=idx0 + j)["t"])
+                            wavestat["emitted"] += e
+                            stats["proposed"] += k_eff
+                            stats["accepted"] += a
+                            stats["emitted_spec"] += e
+                            accepted_round += a
+                            rolled = True
+                        if rolled:
+                            _audit()
+                    if accepted_round == 0:
+                        mismatch_rounds += 1
+                        patience = res["spec_patience"]
+                        if patience is not None \
+                                and mismatch_rounds >= int(patience):
+                            _degrade(f"{mismatch_rounds} consecutive "
+                                     f"all-reject verify rounds")
+                    else:
+                        mismatch_rounds = 0
                 else:
-                    mismatch_rounds = 0
-            else:
-                def _decode_round():
-                    _fire("cow", rids=rids)
-                    cache = manager.batch(rids)
-                    pspec = _fire("decode_step", rids=rids)
-                    tok = jnp.asarray([[active[r]["tok"]] for r in rids],
-                                      jnp.int32)
-                    pos = jnp.asarray([[active[r]["pos"]] for r in rids],
-                                      jnp.int32)
-                    ts = time.perf_counter()
-                    if watchdog is not None:
-                        watchdog.beat()
-                    logits, new_cache = self.decode_vc(
-                        variant, self.params,
-                        {"tokens": tok, "positions": pos}, cache,
-                    )
-                    if watchdog is not None:
-                        watchdog.cancel()
-                    return pspec, ts, logits, new_cache
+                    def _decode_round():
+                        _fire("cow", rids=rids)
+                        cache = manager.batch(rids)
+                        pspec = _fire("decode_step", rids=rids)
+                        with rec.span("serve.dispatch") as sent:
+                            tok = jnp.asarray(
+                                [[active[r]["tok"]] for r in rids], jnp.int32)
+                            pos = jnp.asarray(
+                                [[active[r]["pos"]] for r in rids], jnp.int32)
+                            if watchdog is not None:
+                                watchdog.beat()
+                            logits, new_cache = self.decode_vc(
+                                variant, self.params,
+                                {"tokens": tok, "positions": pos}, cache,
+                            )
+                            if watchdog is not None:
+                                watchdog.cancel()
+                        return pspec, sent, logits, new_cache
 
-                try:
-                    pspec, ts, logits, new_cache = _retry("decode_step",
-                                                          _decode_round)
-                except _StepAbort as err:
-                    aborted = err
-                    break
-                if pspec is not None and pspec.kind == "nan_logits":
-                    vi = rids.index(pspec.rid) if pspec.rid in rids else 0
-                    logits = logits.at[vi].set(jnp.nan)
-                finite = np.asarray(jnp.isfinite(jnp.max(
-                    logits[:, -1].astype(jnp.float32), axis=-1)))
-                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int64)
-                # first step at each batch size pays jit tracing —
-                # excluding it keeps the tuner-feedback observations
-                # compile-free (the DSE expectations were measured
-                # post-compile too)
-                if len(rids) in seen_batches:
-                    dt_step = time.perf_counter() - ts
-                    self.decode_step_latencies.append(dt_step)
-                    self._step_lat_by_batch.setdefault(
-                        len(rids),
-                        deque(maxlen=self.history_window)).append(dt_step)
-                seen_batches.add(len(rids))
-                manager.absorb(rids, new_cache)
-                stats["decode_steps"] += 1
-                hit_nan = False
-                t_tok = time.perf_counter()
-                for i, rid in enumerate(rids):
-                    if not finite[i]:
-                        _quarantine(rid, "non-finite decode logits")
-                        hit_nan = True
-                        continue
-                    idx0 = len(outputs[rid])
-                    outputs[rid].append(int(nxt[i]))
-                    active[rid]["tok"] = int(nxt[i])
-                    active[rid]["pos"] += 1
-                    rq[rid]["tok_t"].append(t_tok)
-                    wavestat["emitted"] += 1
-                    _emit("token", rid=rid, token=int(nxt[i]), index=idx0)
-                if hit_nan:
-                    _audit()
+                    try:
+                        pspec, sent, logits, new_cache = _retry(
+                            "decode_step", _decode_round)
+                    except _StepAbort as err:
+                        aborted = err
+                        break
+                    if pspec is not None and pspec.kind == "nan_logits":
+                        vi = rids.index(pspec.rid) if pspec.rid in rids else 0
+                        logits = logits.at[vi].set(jnp.nan)
+                    with rec.span("serve.wait") as waited:
+                        finite = np.asarray(jnp.isfinite(jnp.max(
+                            logits[:, -1].astype(jnp.float32), axis=-1)))
+                        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
+                                         np.int64)
+                    # first step at each batch size pays jit tracing —
+                    # excluding it keeps the tuner-feedback observations
+                    # compile-free (the DSE expectations were measured
+                    # post-compile too)
+                    if len(rids) in seen_batches:
+                        dt_step = sent.seconds + waited.seconds
+                        self.decode_step_latencies.append(dt_step)
+                        self._step_lat_by_batch.setdefault(
+                            len(rids),
+                            deque(maxlen=self.history_window)).append(dt_step)
+                    seen_batches.add(len(rids))
+                    manager.absorb(rids, new_cache)
+                    stats["decode_steps"] += 1
+                    with rec.span("serve.emit"):
+                        hit_nan = False
+                        for i, rid in enumerate(rids):
+                            if not finite[i]:
+                                _quarantine(rid, "non-finite decode logits")
+                                hit_nan = True
+                                continue
+                            idx0 = len(outputs[rid])
+                            outputs[rid].append(int(nxt[i]))
+                            active[rid]["tok"] = int(nxt[i])
+                            active[rid]["pos"] += 1
+                            wavestat["emitted"] += 1
+                            rq[rid]["tok_t"].append(_emit(
+                                "token", rid=rid, token=int(nxt[i]),
+                                index=idx0)["t"])
+                        if hit_nan:
+                            _audit()
 
-            # wave boundary: one "wave" event carries the batch shape, the
-            # operating point in force, and this wave's emission/prefill
-            # work; the governor observes the same numbers through its
-            # MAPE-K loop (modeled wave latency → Margot.observe)
-            dt_wave = time.perf_counter() - t_wave
-            _emit("wave", batch=len(rids), dt_s=dt_wave,
-                  emitted=wavestat["emitted"],
-                  prefill_tokens=wavestat["prefill_tokens"],
-                  k=(k_eff if S > 1 else 0),
-                  op=(dict(knobs) if gov is not None else None))
-            if gov is not None:
-                gov.observe_wave(dt_wave, batch=len(rids),
-                                 emitted=wavestat["emitted"],
-                                 prefill_tokens=wavestat["prefill_tokens"],
-                                 wave=wave)
-            wave += 1
+                # wave boundary: one "wave" event carries the batch shape, the
+                # operating point in force, and the work since the last wave
+                # event (waves that decoded nothing carry into this one): the
+                # host time by phase, on the span recorder's clock, and the
+                # tokens emitted and prefilled; the governor observes the
+                # same numbers through its MAPE-K loop (Margot.observe)
+                wave_span.set(batch=len(rids))
+                self_s, total_s = rec.take()
+                dt_wave = total_s.get("serve.wave", 0.0)
+                _emit("wave", batch=len(rids), dt_s=dt_wave,
+                      emitted=wavestat["emitted"],
+                      prefill_tokens=wavestat["prefill_tokens"],
+                      k=(k_eff if S > 1 else 0),
+                      op=(dict(knobs) if gov is not None else None),
+                      host_ms=_host_ms(self_s))
+                if gov is not None:
+                    with rec.span("serve.qos"):
+                        gov.observe_wave(
+                            dt_wave, batch=len(rids),
+                            emitted=wavestat["emitted"],
+                            prefill_tokens=wavestat["prefill_tokens"],
+                            wave=wave)
+                wavestat["emitted"] = wavestat["prefill_tokens"] = 0
+                wave += 1
 
         if aborted is not None:
             # a step failed past its retry budget: every in-flight request
