@@ -34,7 +34,7 @@ that with one shared pool of fixed-size pages per layer:
                       every token), splits shared pages copy-on-write
                       before the decode step that would write them, and
                       absorbs the post-step pools / ring `pos` rows /
-                      `kv_pos` rows back into per-request state.
+                      lengths back into per-request state.
 
 The resulting cache pytree is what `Attention._decode`'s paged branch and
 the block-table `flash_decode` kernel consume: per layer `{"pk", "pv"}`
@@ -598,19 +598,11 @@ class PagedCacheManager:
                 "otherwise")
 
     def _new_meta(self, rid, prompt_len: int, final_len: int) -> None:
-        meta: dict[str, Any] = {
+        self._meta[rid] = {
             "length": int(prompt_len),
             "final_len": int(final_len),
             "pos": {},
         }
-        lin = self._linear_len()
-        if lin is not None:
-            ar = jnp.arange(lin, dtype=jnp.int32)
-            meta["kv_pos"] = jnp.where(ar < prompt_len, ar, -1)
-        self._meta[rid] = meta
-
-    def _table_row(self, rid) -> jax.Array:
-        return jnp.asarray(self.pool.table_rows([rid], self.table_width))
 
     @spanned("pages.admit")
     def admit_begin(self, rid, tokens, *, final_len: int,
@@ -656,14 +648,17 @@ class PagedCacheManager:
         must be written by exactly one prefill dispatch for the pool bytes
         to match a one-shot prefill bit-for-bit.
         """
+        idx = np.full((1,), resident, np.int32)
+        host: dict[str, Any] = {
+            name: np.tile(idx, (info["n"], 1)) if info["scanned"] else idx
+            for name, info in self._groups.items()
+        }
+        host["block_tables"] = self.pool.table_rows([rid], self.table_width)
+        dev = jax.device_put(host, self.device)
         view: dict[str, Any] = {}
         for name, info in self._groups.items():
             group: dict[str, Any] = dict(self._pools[name])
-            idx = np.full((1,), resident, np.int32)
-            if info["scanned"]:
-                group["index"] = jnp.asarray(np.tile(idx, (info["n"], 1)))
-            else:
-                group["index"] = jnp.asarray(idx)
+            group["index"] = dev[name]
             if info["ring"]:
                 W = info["length"]
                 shape = (info["n"], W) if info["scanned"] else (W,)
@@ -671,7 +666,7 @@ class PagedCacheManager:
                 group["pos"] = jnp.full(shape, -1, jnp.int32) \
                     if pos is None else pos
             view[name] = group
-        view["block_tables"] = self._table_row(rid)
+        view["block_tables"] = dev["block_tables"]
         return view
 
     @spanned("pages.absorb_prefill")
@@ -822,8 +817,6 @@ class PagedCacheManager:
         for name, info in self._groups.items():
             if info["ring"]:
                 meta["pos"][name] = cache[name]["pos"]  # (W,) or (n, W)
-        if "kv_pos" in cache:
-            meta["kv_pos"] = cache["kv_pos"][0]  # (max_len,)
         self._meta[rid] = meta
 
     @spanned("pages.release")
@@ -912,48 +905,49 @@ class PagedCacheManager:
         return self._compose(rids)
 
     def _compose(self, rids: list[Any], *, index_offset: int = 0) -> dict:
-        lengths = np.asarray(
-            [self._meta[r]["length"] + index_offset for r in rids], np.int32)
-        tables = jnp.asarray(self.pool.table_rows(rids, self.table_width))
+        lengths = np.asarray([self._meta[r]["length"] for r in rids],
+                             np.int32)
+        index = lengths + np.int32(index_offset)
+        # every per-wave host array goes up in one transfer: each group's
+        # `index`, the block tables and, for a pool with a linear group,
+        # the hoisted (B, T) `kv_pos` map — slot s holds position s while
+        # s < length, -1 beyond, which is all the decode steps' scatter
+        # ever writes, so it follows from the host lengths alone
+        host: dict[str, Any] = {
+            name: np.tile(index, (info["n"], 1)) if info["scanned"] else index
+            for name, info in self._groups.items()
+        }
+        host["block_tables"] = self.pool.table_rows(rids, self.table_width)
+        lin = self._linear_len()
+        if lin is not None:
+            ar = np.arange(lin, dtype=np.int32)
+            host["kv_pos"] = np.where(ar[None] < lengths[:, None], ar[None],
+                                      np.int32(-1))
+        dev = jax.device_put(host, self.device)
 
         cache: dict[str, Any] = {}
         for name, info in self._groups.items():
             group: dict[str, Any] = dict(self._pools[name])
-            if info["scanned"]:
-                group["index"] = jnp.asarray(
-                    np.tile(lengths, (info["n"], 1)))
-            else:
-                group["index"] = jnp.asarray(lengths)
+            group["index"] = dev[name]
             if info["ring"]:
                 rows = [self._meta[r]["pos"][name] for r in rids]
                 group["pos"] = jnp.stack(rows,
                                          axis=1 if info["scanned"] else 0)
             cache[name] = group
-        cache["block_tables"] = tables
-        if any("kv_pos" in self._meta[r] for r in rids):
-            rows = []
-            for r in rids:
-                kvp = self._meta[r].get("kv_pos")
-                if kvp is None:
-                    # a legacy admit() of a hand-built cache may lack the
-                    # hoisted map; synthesize it (slot s -> s while live —
-                    # exactly what the decode steps would have maintained)
-                    width = self._linear_len() or self.max_len
-                    ar = jnp.arange(int(width), dtype=jnp.int32)
-                    kvp = jnp.where(ar < self._meta[r]["length"], ar, -1)
-                    self._meta[r]["kv_pos"] = kvp
-                rows.append(kvp)
-            cache["kv_pos"] = jnp.stack(rows, axis=0)
+        cache["block_tables"] = dev["block_tables"]
+        if lin is not None:
+            cache["kv_pos"] = dev["kv_pos"]
         return cache
 
     @spanned("pages.absorb")
     def absorb(self, rids: list[Any], new_cache: dict, *,
                advance: int = 1) -> None:
         """Store one decode step's outputs back: pools are shared (one
-        assignment), per-request rows split on their batch axis.  A
-        speculative verify step passes `advance` = its q span so lengths
-        provisionally cover the whole draft block (rollback() then trims
-        rejected tokens)."""
+        assignment), ring `pos` rows split on their batch axis, lengths
+        advance.  The step's `kv_pos` is dropped: `_compose` rebuilds it
+        from the lengths.  A speculative verify step passes `advance` =
+        its q span so lengths provisionally cover the whole draft block
+        (rollback() then trims rejected tokens)."""
         for name, info in self._groups.items():
             group = new_cache[name]
             self._pools[name] = self._pool_state(group)
@@ -962,9 +956,6 @@ class PagedCacheManager:
                 for i, rid in enumerate(rids):
                     self._meta[rid]["pos"][name] = jnp.take(
                         group["pos"], i, axis=axis)
-        if "kv_pos" in new_cache:
-            for i, rid in enumerate(rids):
-                self._meta[rid]["kv_pos"] = new_cache["kv_pos"][i]
         for rid in rids:
             self._meta[rid]["length"] += advance
 
@@ -975,8 +966,9 @@ class PagedCacheManager:
         Table entries past the slots `new_length` needs are released
         tail-first (refcount decrement — donor pages shared with other
         requests just lose this reference, their bytes are never touched
-        or copied), freed pages are purged from the prefix index, and the
-        hoisted `kv_pos` map is rewound so stale draft slots mask dead.
+        or copied) and freed pages are purged from the prefix index; the
+        next `_compose` builds `kv_pos` from the new length, so stale draft
+        slots mask dead.
         The over-written K/V bytes in still-held pages are left in place:
         they sit past the live boundary, so attention never reads them and
         the next decode step overwrites them.  CoW splits performed for
@@ -993,10 +985,6 @@ class PagedCacheManager:
         if freed:
             self._purge_keys(freed)
             self._pop_scales(freed)
-        if "kv_pos" in m:
-            kvp = m["kv_pos"]
-            ar = jnp.arange(kvp.shape[-1], dtype=jnp.int32)
-            m["kv_pos"] = jnp.where(ar < new_length, kvp, -1)
         return freed
 
     # -- introspection -----------------------------------------------------------

@@ -773,3 +773,158 @@ class TestPrefixSharing:
                     cache)
                 np.testing.assert_array_equal(paged_logits[rid][step],
                                               np.asarray(logits[0]))
+
+
+def _legacy_cache(manager, length: int) -> dict:
+    """A hand-built dense (batch=1) cache with no hoisted `kv_pos`,
+    matching the pool's groups, `length` slots filled."""
+    import jax.numpy as jnp
+
+    legacy = {}
+    for name, info in manager._groups.items():
+        shape = (info["n"], 1, info["length"], info["kv_heads"],
+                 info["head_dim"])
+        legacy[name] = {
+            "k": jnp.zeros(shape, info["dtype"]),
+            "v": jnp.zeros(shape, info["dtype"]),
+            "index": jnp.full((info["n"],), length, jnp.int32),
+        }
+    return legacy
+
+
+class TestComposedKvPos:
+    """The pool keeps only host lengths per request and builds each
+    wave's (B, T) `kv_pos` map from them.  Every composed row must equal
+    the row the previous step returned for that request — the row a pool
+    that stored the step's `kv_pos` per request would hand back."""
+
+    T = 24
+
+    def _check(self, manager, rids, expect, cache=None):
+        cache = manager.batch(rids) if cache is None else cache
+        got = np.asarray(cache["kv_pos"])
+        assert got.shape == (len(rids), self.T)
+        for i, rid in enumerate(rids):
+            np.testing.assert_array_equal(got[i], expect[rid],
+                                          err_msg=f"request {rid}")
+        return cache
+
+    def _decode(self, srv, manager, rids, expect):
+        """One real decode step: the composed map must match `expect`,
+        and the step's returned rows become the new expectation."""
+        import jax.numpy as jnp
+
+        cache = self._check(manager, rids, expect)
+        pos = [[manager._meta[r]["length"]] for r in rids]
+        _, new_cache = srv.decode_vc(
+            None, srv.params,
+            {"tokens": jnp.full((len(rids), 1), 7, jnp.int32),
+             "positions": jnp.asarray(pos, jnp.int32)}, cache)
+        ret = np.asarray(new_cache["kv_pos"])
+        manager.absorb(rids, new_cache)
+        for i, rid in enumerate(rids):
+            expect[rid] = ret[i]
+
+    @pytest.mark.parametrize("second", ["direct", "chunked", "shared",
+                                        "legacy"])
+    def test_composed_rows_match_step_rows(self, second):
+        import jax.numpy as jnp
+
+        srv = _server("yi-6b")
+        srv.woven.variant_state(None).extra["cache_max_len"] = self.T
+        manager = PagedCacheManager(8, 8, max_len=self.T, window=None)
+        ar = np.arange(self.T)
+        p0 = np.array([3, 1, 4, 1, 5], np.int32)
+        srv._paged_admit(manager, 0, p0, 20, None)
+        expect = {0: np.where(ar < 5, ar, -1)}
+
+        if second == "chunked":
+            # an 18-token prompt in 8-token chunks, a decode of request 0
+            # between chunks
+            p1 = (np.arange(18) % 50 + 1).astype(np.int32)
+            tok, _, cont = srv._paged_admit_chunked(manager, 1, p1, 24,
+                                                    None, chunk=8)
+            assert tok is None and cont is not None
+            while tok is None:
+                self._decode(srv, manager, [0], expect)
+                tok = cont()["tok"]
+            S1 = 18
+        elif second == "legacy":
+            manager.admit(1, _legacy_cache(manager, 4), final_len=16)
+            S1 = 4
+        else:
+            p1 = p0 if second == "shared" else np.arange(1, 10,
+                                                         dtype=np.int32)
+            srv._paged_admit(manager, 1, p1, 20, None)
+            S1 = len(p1)
+        if second == "shared":
+            assert manager.prefix_hits >= 1
+            # the re-score view masks as the admission left it
+            self._check(manager, [1], {1: np.where(ar < 5, ar, -1)},
+                        cache=manager.rescore_view(1))
+        expect[1] = np.where(ar < S1, ar, -1)
+
+        for _ in range(2):
+            self._decode(srv, manager, [0, 1], expect)
+
+        # a verify step writes a 3-token draft block, lengths advance by 3
+        rids = [0, 1]
+        cache = self._check(manager, rids, expect,
+                            cache=manager.batch(rids, tokens=3))
+        vpos = [[manager._meta[r]["length"] + s for s in range(3)]
+                for r in rids]
+        _, new_cache = srv._verify_step(None, 2)(
+            srv.params, {"tokens": jnp.full((2, 3), 7, jnp.int32),
+                         "positions": jnp.asarray(vpos, jnp.int32)}, cache)
+        ret = np.asarray(new_cache["kv_pos"])
+        manager.absorb(rids, new_cache, advance=3)
+        expect = {r: ret[i] for i, r in enumerate(rids)}
+        self._check(manager, rids, expect)
+
+        # rollback rewinds: slots at or past the new length mask dead
+        for rid, back in ((0, 2), (1, 1)):
+            nl = manager._meta[rid]["length"] - back
+            manager.rollback(rid, nl)
+            expect[rid] = np.where(ar < nl, expect[rid], -1)
+        self._decode(srv, manager, [1, 0], expect)
+        self._check(manager, [0, 1], expect)
+
+        # the re-score view of a decoding request keeps the decode map
+        self._check(manager, [0], expect, cache=manager.rescore_view(0))
+
+    def test_absorb_keeps_no_per_request_device_state(self):
+        """Linear pools hold host ints per request, never a device array:
+        no `kv_pos` row is stored after admission, decode or rollback."""
+        import jax.numpy as jnp
+
+        srv = _server("yi-6b")
+        manager = PagedCacheManager(8, 8, max_len=self.T, window=None)
+        for rid, n in ((0, 5), (1, 9)):
+            srv._paged_admit(manager, rid, np.arange(1, n + 1,
+                                                     dtype=np.int32),
+                             16, None)
+        manager.admit(2, _legacy_cache(manager, 4), final_len=12)
+        rids = [0, 1, 2]
+
+        def no_device_rows():
+            for rid in rids:
+                meta = manager._meta[rid]
+                assert "kv_pos" not in meta
+                assert meta["pos"] == {}
+                assert not any(isinstance(v, jax.Array)
+                               for v in meta.values())
+
+        no_device_rows()
+        for _ in range(2):
+            cache = manager.batch(rids)
+            pos = [[manager._meta[r]["length"]] for r in rids]
+            _, new_cache = srv.decode_vc(
+                None, srv.params,
+                {"tokens": jnp.full((3, 1), 7, jnp.int32),
+                 "positions": jnp.asarray(pos, jnp.int32)}, cache)
+            assert "kv_pos" in new_cache  # the step still returns a map
+            manager.absorb(rids, new_cache)
+            no_device_rows()
+        manager.rollback(1, 9)
+        no_device_rows()
+        assert [manager._meta[r]["length"] for r in rids] == [7, 9, 6]

@@ -242,18 +242,18 @@ class TestRollback:
             manager.absorb([0], cache, advance=3)
         assert manager._meta[0]["length"] == 11
         assert len(manager.pool.tables[0]) == 2
-        # a real verify step would have marked the written slots live in
-        # the hoisted kv_pos map; the identity absorb above didn't — set
-        # it so the rewind below is observable
-        ar = jnp.arange(24, dtype=jnp.int32)
-        manager._meta[0]["kv_pos"] = jnp.where(ar < 11, ar, -1)
+        ar = np.arange(24)
+        np.testing.assert_array_equal(
+            np.asarray(manager.batch([0])["kv_pos"][0]),
+            np.where(ar < 11, ar, -1))
         freed = manager.rollback(0, 6)
         assert len(freed) == 1  # the grown tail page came back
         assert len(manager.pool.tables[0]) == 1
         assert manager._meta[0]["length"] == 6
-        kvp = np.asarray(manager._meta[0]["kv_pos"])
-        ar = np.arange(kvp.shape[-1])
-        np.testing.assert_array_equal(kvp, np.where(ar < 6, ar, -1))
+        # the next composed map masks the rejected draft slots dead
+        kvp = np.asarray(manager.batch([0])["kv_pos"])
+        assert kvp.shape == (1, 24)
+        np.testing.assert_array_equal(kvp[0], np.where(ar < 6, ar, -1))
         with pytest.raises(ValueError):
             manager.rollback(0, 7)  # beyond the live length
         with pytest.raises(ValueError):
