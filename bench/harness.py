@@ -2,10 +2,15 @@
 
 The system under test is `repro.runtime.server.Server.serve_stream` under
 the program's default weave (`repro.launch.weave.default_weave`), as a user
-serves it.  The benchmark gives it weights drawn from the seed
-(`weights.py`) and a request stream drawn from the seed (`mix.py`), and
-reads what it emits: events stamped on the host clock, its pool counters,
-and, in a traced run, the profiler's device trace.
+serves it.  The benchmark gives it weights drawn from the seed and a
+request stream drawn from the seed (`mix.py`), and reads what it emits:
+events stamped on the host clock, its pool counters, and, in a traced run,
+the profiler's device trace.
+
+Everything that depends on the model's architecture comes from the family
+that the configuration file names (`"family"`): the module
+`families/<family>.py`, loaded by path, gives the functions of FAMILY_API.
+The harness knows no architecture.
 
 Time line of a run, all in one process:
   set-up   build weights and server, serve the warm-up streams (every step
@@ -15,8 +20,8 @@ Time line of a run, all in one process:
   window   from the arrival of the first timed request, `--seconds` long;
   drain    requests that arrived in the window are served to their end,
            later arrivals are turned away (the stream's preemption drain);
-  check    the program's state is freed and the plain reference scores a
-           sample of the served requests (`reference.py`).
+  check    the program's state is freed and the family's plain reference
+           scores a sample of the served requests (`reference.py`).
 """
 
 from __future__ import annotations
@@ -24,6 +29,7 @@ from __future__ import annotations
 import bisect
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -43,8 +49,7 @@ if str(REPO / "src") not in sys.path:
 import jax  # noqa: E402
 
 from bench import mix as mixlib  # noqa: E402
-from bench import reference, tracing, weights, yardstick  # noqa: E402
-from bench import warmmark  # noqa: E402
+from bench import reference, tracing, warmmark  # noqa: E402
 
 # arrivals are generated for this many waves per second of window: more
 # waves than a step that streams these models' weights can run
@@ -53,6 +58,22 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 WINDOW_SPAN = "bench.window"
 TRACE_LEAD_WAVES = 3
 NEXT_SPAN = "bench.serve_stream.next"
+FAMILIES = BENCH / "families"
+# what a family module gives, by name:
+#   dims(conf)             the model's dimensions, a flat dict that carries
+#                          the vocabulary size "V" the traffic draws from
+#   toy(conf)              the configuration cut to toy widths (knee.py)
+#   model_config(conf)     the program's ModelConfig
+#   canonical(dm, seed, device)          every weight, drawn from the seed
+#   program_params(canon, abstract)      the weights as the program's tree
+#   served_logits(canon, dm, seqs, control=False)
+#                          the float32 reference (`reference.over_sequences`)
+#                          and, with `control`, its float8 control
+#   forward_flops(dm, start, stop)       operations for tokens start..stop-1
+#   decode_bytes(dm, kv_lens)            least bytes of a decode step, or
+#                          None where lengths alone cannot give them
+FAMILY_API = ("dims", "toy", "model_config", "canonical", "program_params",
+              "served_logits", "forward_flops", "decode_bytes")
 
 
 class NoChip(RuntimeError):
@@ -70,6 +91,22 @@ def load_json(*parts: str) -> dict:
     return json.loads(BENCH.joinpath(*parts).read_text())
 
 
+@functools.cache
+def load_family(name: str, directory: Path = FAMILIES):
+    """The family module `<directory>/<name>.py`, loaded once by path."""
+    path = directory / f"{name}.py"
+    if not path.is_file():
+        raise RunError(f"no family module {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_family_{name}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    missing = [f for f in FAMILY_API if not callable(getattr(module, f, None))]
+    if missing:
+        raise RunError(f"family {name} lacks {missing}")
+    return module
+
+
 @dataclasses.dataclass
 class Cell:
     """Everything one workload of BENCHMARK.json names."""
@@ -81,10 +118,18 @@ class Cell:
     limits: dict
     end_to_end: list[dict]
     per_layer: list[dict]
+    families: Path = FAMILIES  # where the configuration's family is found
+
+    @property
+    def family(self):
+        if "family" not in self.config:
+            raise RunError(f"configuration {self.config.get('name')!r} "
+                           "names no family")
+        return load_family(self.config["family"], self.families)
 
     @property
     def dm(self) -> dict:
-        return weights.dims(self.config)
+        return self.family.dims(self.config)
 
     @property
     def server(self) -> dict:
@@ -142,18 +187,6 @@ def devices_for(chips: int, require_tpu: bool = True) -> list:
     return devs
 
 
-def model_config(conf: dict):
-    """The program's ModelConfig at the configuration file's numbers."""
-    from repro.models.registry import get_config
-
-    dm = weights.dims(conf)
-    return get_config(conf["registry"]).replace(
-        name=conf["name"], num_layers=dm["L"], d_model=dm["d"],
-        n_heads=dm["H"], kv_heads=dm["K"], head_dim=dm["D"], d_ff=dm["F"],
-        vocab=dm["V"], rope_theta=dm["theta"], tie_embeddings=dm["tied"],
-        qkv_bias=dm["bias"], activation=conf["hidden_act"], layer_groups=())
-
-
 def build_server(cell: Cell, seed: int, device):
     """A Server over the cell's configuration, serving the benchmark's
     weights for `seed`.  Server has no way to take weights, so its own
@@ -166,12 +199,13 @@ def build_server(cell: Cell, seed: int, device):
     from repro.nn.module import abstract_params
     from repro.runtime import server as server_mod
 
-    mc = model_config(cell.config)
+    fam = cell.family
+    mc = fam.model_config(cell.config)
     program = Program(model=build_model(mc), cfg=mc, kind="serve")
     woven = default_weave(program, SHAPES["prefill_32k"], {})
     abstract = abstract_params(program.model, woven.state.policies)
-    params = weights.program_params(
-        weights.canonical(cell.dm, seed, device), abstract)
+    params = fam.program_params(fam.canonical(cell.dm, seed, device),
+                                abstract)
     sc = cell.server
     cfg = server_mod.ServerConfig(
         max_cache_len=cell.max_cache_len,
@@ -188,8 +222,9 @@ def build_server(cell: Cell, seed: int, device):
 def set_weights(server, cell: Cell, seed: int, device, abstract) -> None:
     server.params = None
     gc.collect()
-    server.params = weights.program_params(
-        weights.canonical(cell.dm, seed, device), abstract)
+    fam = cell.family
+    server.params = fam.program_params(fam.canonical(cell.dm, seed, device),
+                                       abstract)
 
 
 class PrefillTap:
@@ -448,14 +483,14 @@ class Run:
         return [by_wave[w] for w in sorted(by_wave)]
 
     def window_flops(self) -> float:
-        dm = self.dm
+        dm, flops = self.dm, self.cell.family.forward_flops
         total = 0.0
         for t, rid, idx in self.tokens:
             if idx >= 1 and self.in_window(t):
                 p = self.lengths[rid] + idx - 1
-                total += yardstick.forward_flops(dm, p, p + 1)
+                total += flops(dm, p, p + 1)
         for _, prefix, n in self.prefills:
-            total += yardstick.forward_flops(dm, prefix, prefix + n)
+            total += flops(dm, prefix, prefix + n)
         return total
 
 
@@ -512,14 +547,15 @@ def score(cell: Cell, seed: int, prompts, served, device, *,
           control: bool = False) -> dict:
     """The reference's verdict on served tokens: the widest gap below its
     best logit; with `control`, also the gap of the control's own choices."""
-    canon = weights.canonical(cell.dm, seed, device)
+    fam = cell.family
+    canon = fam.canonical(cell.dm, seed, device)
     seqs = [(np.concatenate([p, s[:-1]]), len(p) - 1)
             for p, s in zip(prompts, served)]
-    ref = reference.served_logits(canon, cell.dm, seqs)
+    ref = fam.served_logits(canon, cell.dm, seqs)
     out = {"served_gap_max": reference.served_gap(ref, served),
            "tokens_scored": int(sum(len(s) for s in served))}
     if control:
-        ctl = reference.served_logits(canon, cell.dm, seqs, control=True)
+        ctl = fam.served_logits(canon, cell.dm, seqs, control=True)
         out["control_gap_max"] = reference.served_gap(
             ref, [c.argmax(-1) for c in ctl])
     del canon

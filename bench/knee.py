@@ -8,11 +8,12 @@ Arrivals run on serve_stream's wave clock and every request decodes the
 same number of tokens, so how many requests a wave admits depends only on
 the scheduler: prompt lengths, page size, pool pages, max_batch and the
 prefill chunk.  This runs the cell's traffic and server settings through
-`Server.serve_stream` on the CPU with the model cut to toy widths (which
-changes no scheduling decision) and prints, per offered rate, the
-admissions per wave over the second half of the run and the queue left at
-its end.  Where admissions stop following the offered rate, the queue
-grows: that rate is the knee.  Times here are of the CPU and mean nothing.
+`Server.serve_stream` on the CPU with the model cut to toy widths by its
+family's `toy` (which changes no scheduling decision) and prints, per
+offered rate, the admissions per wave over the second half of the run and
+the queue left at its end.  Where admissions stop following the offered
+rate, the queue grows: that rate is the knee.  Times here are of the CPU
+and mean nothing.
 """
 
 from __future__ import annotations
@@ -24,10 +25,6 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-TOY = {"hidden_size": 32, "intermediate_size": 64, "num_attention_heads": 2,
-       "num_key_value_heads": 1, "head_dim": 16, "num_hidden_layers": 1,
-       "vocab_size": 256}
-
 
 def sweep(cell, rate: float, waves: int, seed: int = 0) -> dict:
     import jax
@@ -36,7 +33,7 @@ def sweep(cell, rate: float, waves: int, seed: int = 0) -> dict:
     from bench import mix as mixlib
 
     mix = dict(cell.mix, rate_per_wave=rate, preroll_waves=0)
-    stream = mixlib.timed_stream(mix, seed, TOY["vocab_size"], waves)
+    stream = mixlib.timed_stream(mix, seed, cell.dm["V"], waves)
     server, _ = harness.build_server(cell, seed, jax.devices()[0])
     gen = server.serve_stream(stream.prompts,
                               arrival_waves=stream.arrival_waves,
@@ -69,7 +66,7 @@ def main() -> int:
     from bench import harness
 
     cell = harness.load_cell(args.workload)
-    cell.config = dict(cell.config, **TOY)
+    cell.config = cell.family.toy(cell.config)
     for rate in args.rates:
         print(json.dumps(sweep(cell, rate, args.waves)), flush=True)
     return 0

@@ -1,6 +1,7 @@
 """Model FLOP utilisation of the whole serving step: the operations the
 model needs for every prefill and decode token computed in the window
-(bench/yardstick.py) over the window times the chip's bf16 peak."""
+(`Run.window_flops`, by the family's `forward_flops`) over the window
+times the chip's bf16 peak."""
 
 
 def read(run):

@@ -1,25 +1,20 @@
-"""Plain reference: the published Llama / Qwen2 decoder in float32.
+"""Plain float32 building blocks that the families' references share, and
+the comparison that decides `correct`.
 
-It imports nothing of the program.  Per layer: RMSNorm, q/k/v projections
-(with biases for Qwen2), rotate-half RoPE, causal grouped-query attention
-(query head h reads key/value head h // (H / K)), output projection and
-residual; RMSNorm, SiLU-gated MLP and residual.  A final RMSNorm and the
-untied output head give the logits.  Every matrix product runs in float32
+They import nothing of the program.  Every matrix product runs in float32
 at `HIGHEST` precision (a TPU multiplies float32 in bfloat16 otherwise).
-
-It runs after the program's state is freed, one sequence at a time and one
-layer at a time, attention in blocks of query rows and the matrices in
-blocks of token rows, so it fits one chip beside the bfloat16 weights.
+A family's reference runs after the program's state is freed, one
+sequence at a time and one layer at a time (`over_sequences`), attention
+in blocks of query rows and the matrices in blocks of token rows (`rows`),
+so it fits one chip beside the bfloat16 weights.
 
 `control=True` is the precision control: every matrix product takes its
 operands rounded to float8 e4m3 (weights scaled per output column,
-activations per token row), the step below the bfloat16 the configuration
-states.
+activations per token row), the step below the bfloat16 the configurations
+state.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -38,19 +33,20 @@ def _fp8(x, axis):
     return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
 
 
-def _dot(x, w, control: bool):
+def dot(x, w, control: bool):
+    """x @ w in float32; with `control`, both operands rounded to float8."""
     w = w.astype(jnp.float32)
     if control:
         x, w = _fp8(x, -1), _fp8(w, 0)
     return jnp.dot(x, w, precision=HIGHEST)
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x: (S, heads, D) rotated by position, the two halves of each head
     as the real and imaginary parts."""
     half = x.shape[-1] // 2
@@ -61,32 +57,19 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-def _rows(fn, x):
+def rows(fn, x):
+    """`fn` over blocks of ROW_BLOCK token rows of x (S, ...)."""
     S = x.shape[0]
     out = jax.lax.map(fn, x.reshape(S // ROW_BLOCK, ROW_BLOCK, x.shape[-1]))
     return out.reshape(S, -1)
 
 
-@functools.partial(jax.jit, static_argnames=("dm_items", "control"))
-def _layer(h, layer, W, *, dm_items, control):
-    dm = dict(dm_items)
-    H, K, D, eps = dm["H"], dm["K"], dm["D"], dm["eps"]
+def causal_attention(q, k, v, pos):
+    """Causal grouped-query attention of q (S, H, D) over k, v (S, K, D):
+    query head h reads key/value head h // (H / K).  Returns (S, H * D)."""
+    S, H, D = q.shape
+    K = k.shape[1]
     G = H // K
-    S = h.shape[0]
-    w = {n: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
-         for n, a in W.items()}
-    pos = jnp.arange(S, dtype=jnp.int32)
-
-    x = _rms(h, w["norm1"], eps)
-
-    def proj(name, heads):
-        y = _rows(lambda r: _dot(r, w["w" + name], control), x)
-        if dm["bias"]:
-            y = y + w["b" + name].astype(jnp.float32)
-        return _rope(y.reshape(S, heads, D), pos, dm["theta"]) \
-            if name in ("q", "k") else y.reshape(S, heads, D)
-
-    q, k, v = proj("q", H), proj("k", K), proj("v", K)
     kg = k.transpose(1, 0, 2)                      # (K, S, D)
     vg = v.transpose(1, 0, 2)
 
@@ -102,36 +85,18 @@ def _layer(h, layer, W, *, dm_items, control):
         return o.transpose(2, 0, 1, 3).reshape(-1, H * D)
 
     nq = S // Q_BLOCK
-    o = jax.lax.map(attend, (q.reshape(nq, Q_BLOCK, H, D),
-                             pos.reshape(nq, Q_BLOCK))).reshape(S, H * D)
-    h = h + _rows(lambda r: _dot(r, w["wo"], control), o)
-
-    x = _rms(h, w["norm2"], eps)
-
-    def mlp(r):
-        g = _dot(r, w["w_gate"], control)
-        u = _dot(r, w["w_up"], control)
-        return _dot(jax.nn.silu(g) * u, w["w_down"], control)
-
-    return h + _rows(mlp, x)
+    return jax.lax.map(attend, (q.reshape(nq, Q_BLOCK, H, D),
+                                pos.reshape(nq, Q_BLOCK))).reshape(S, H * D)
 
 
-@functools.partial(jax.jit, static_argnames=("dm_items", "control"))
-def _head(h, final_norm, head, *, dm_items, control):
-    dm = dict(dm_items)
-    return _dot(_rms(h, final_norm, dm["eps"]), head, control)
-
-
-def served_logits(canon: dict, dm: dict, seqs, *, control: bool = False):
+def over_sequences(seqs, forward):
     """Logits at the positions that predicted served tokens.
 
     `seqs`: (tokens, first) pairs, tokens the prompt followed by the served
     tokens but the last, `first` the position of the last prompt token.
-    Returns one (len(tokens) - first, vocab) float32 array per pair."""
-    dm_items = tuple(sorted(dm.items()))
-    layer_w = {n: a for n, a in canon.items()
-               if n not in ("embed", "final_norm", "head")}
-    head = canon["embed"].T if dm["tied"] else canon["head"]
+    `forward(ids, first, n)` gives the float32 logits of rows first..n-1 of
+    `ids`, the tokens zero-padded to a multiple of PAD.  Returns one
+    (len(tokens) - first, vocab) float32 array per pair."""
     out = []
     with jax.default_matmul_precision("highest"):
         for tokens, first in seqs:
@@ -139,14 +104,7 @@ def served_logits(canon: dict, dm: dict, seqs, *, control: bool = False):
             S = -(-n // PAD) * PAD
             ids = np.zeros(S, np.int32)
             ids[:n] = tokens
-            h = jnp.take(canon["embed"], jnp.asarray(ids), axis=0
-                         ).astype(jnp.float32)
-            for layer in range(dm["L"]):
-                h = _layer(h, layer, layer_w, dm_items=dm_items,
-                           control=control)
-            logits = _head(h[first:n], canon["final_norm"], head,
-                           dm_items=dm_items, control=control)
-            out.append(np.asarray(logits))
+            out.append(np.asarray(forward(jnp.asarray(ids), first, n)))
     return out
 
 
@@ -155,6 +113,6 @@ def served_gap(ref_logits, served) -> float:
     reference's best at that position."""
     worst = 0.0
     for ref, toks in zip(ref_logits, served):
-        rows = np.arange(len(toks))
-        worst = max(worst, float(np.max(ref.max(-1) - ref[rows, toks])))
+        at = np.arange(len(toks))
+        worst = max(worst, float(np.max(ref.max(-1) - ref[at, toks])))
     return worst

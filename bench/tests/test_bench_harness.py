@@ -130,6 +130,10 @@ def test_benchmark_names_resolve():
         conf = json.loads((harness.REPO / c["file"]).read_text())
         assert conf["source"] == c["source"]
         assert sorted(conf["reduced"]) == sorted(c["reduced"])
+        family = harness.load_family(conf["family"])
+        for fn in harness.FAMILY_API:
+            assert callable(getattr(family, fn)), (conf["family"], fn)
+        assert family.dims(conf)["V"] == conf["vocab_size"]
 
 
 def test_warm_mark_follows_the_cache(tmp_path, monkeypatch):

@@ -1,6 +1,6 @@
 """The metric arithmetic: percentiles over all requests with misses in
 the tail, rates over the whole window, and yi-6b's bytes and operations
-against numbers worked out by hand."""
+(its family's yardstick) against numbers worked out by hand."""
 
 import json
 import math
@@ -10,10 +10,11 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from bench import harness, weights, yardstick
+from bench import harness, yardstick
 
 BENCH = Path(__file__).resolve().parents[1]
-YI = weights.dims(json.loads((BENCH / "configs" / "yi-6b.json").read_text()))
+DENSE = harness.load_family("dense_gqa")
+YI = DENSE.dims(json.loads((BENCH / "configs" / "yi-6b.json").read_text()))
 
 
 def test_percentile_nearest_rank():
@@ -67,20 +68,20 @@ def test_yi6b_decode_bytes_by_hand():
     layer = 16_777_216 + 4_194_304 + 16_777_216 + 135_266_304
     assert layer == 173_015_040
     matrices = 32 * layer + 4096 * 64000
-    assert yardstick.matrix_params(YI) == matrices == 5_798_625_280
-    assert yardstick.kv_bytes_per_token(YI) == 65_536  # 64 KiB
+    assert DENSE.matrix_params(YI) == matrices == 5_798_625_280
+    assert DENSE.kv_bytes_per_token(YI) == 65_536  # 64 KiB
     weights_b = 2 * matrices + 65 * 4096 * 4
-    assert yardstick.weight_bytes(YI) == weights_b == 11_598_315_520
-    assert yardstick.decode_bytes(YI, [100, 200]) == \
+    assert DENSE.weight_bytes(YI) == weights_b == 11_598_315_520
+    assert DENSE.decode_bytes(YI, [100, 200]) == \
         weights_b + 2 * 4096 * 2 + 300 * 65_536 + 2 * 65_536
 
 
 def test_yi6b_flops_by_hand():
-    one = yardstick.forward_flops(YI, 0, 1)
+    one = DENSE.forward_flops(YI, 0, 1)
     assert one == 2 * 5_798_625_280 + 4 * 32 * 32 * 128 * 1
     # a 4-token chunk after 2 resident tokens attends 3+4+5+6 keys
-    assert yardstick.attention_flops(YI, 2, 6) == 4 * 32 * 32 * 128 * 18
-    assert yardstick.forward_flops(YI, 2, 6) == \
+    assert DENSE.attention_flops(YI, 2, 6) == 4 * 32 * 32 * 128 * 18
+    assert DENSE.forward_flops(YI, 2, 6) == \
         4 * 2 * 5_798_625_280 + 4 * 32 * 32 * 128 * 18
 
 

@@ -1,13 +1,17 @@
-"""Weights of a configuration, drawn on the device from `--seed`.
+"""Weights of a configuration, drawn on the device from `--seed`: the part
+that every family (`families/<name>.py`) shares.
 
-`canonical(dims, seed)` builds every tensor of the model under plain names
-(`embed`, `wq`, ..., `head`; per-layer tensors stacked on a leading layer
-axis) in one jitted call, in the dtype they are served in.  The seed is a
-traced argument, so the call compiles once for all seeds, and a second call
-with the same seed gives the same bits: the program serves the first set
-and the reference (`reference.py`) rebuilds its own from the seed once the
-program's state is freed.  `program_params` lays the same arrays out as the
-program's parameter tree.
+A family's `canonical(dm, seed, device)` hands `canonical` below a `make`
+function that builds every tensor of its model under plain names (per-layer
+tensors stacked on a leading layer axis, `draw_stack`) from a seed's two
+words, each tensor drawn from its own key (`name_key`) with the spread its
+role asks for (`draw`).  `canonical` runs `make` as one jitted call, in the
+dtype the weights are served in.  The seed is a traced argument, so the
+call compiles once for all seeds, and a second call with the same seed
+gives the same bits: the program serves the first set and the reference
+rebuilds its own from the seed once the program's state is freed.  `place`
+lays the same arrays out as the program's parameter tree, by a family's
+table of paths.
 """
 
 from __future__ import annotations
@@ -19,121 +23,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# per-layer tensors in plain names: shape as a function of the dimensions
-LAYER_SHAPES = {
-    "norm1": lambda d: (d["d"],),
-    "wq": lambda d: (d["d"], d["H"] * d["D"]),
-    "wk": lambda d: (d["d"], d["K"] * d["D"]),
-    "wv": lambda d: (d["d"], d["K"] * d["D"]),
-    "wo": lambda d: (d["H"] * d["D"], d["d"]),
-    "norm2": lambda d: (d["d"],),
-    "w_gate": lambda d: (d["d"], d["F"]),
-    "w_up": lambda d: (d["d"], d["F"]),
-    "w_down": lambda d: (d["F"], d["d"]),
-}
-BIAS_SHAPES = {
-    "bq": lambda d: (d["H"] * d["D"],),
-    "bk": lambda d: (d["K"] * d["D"],),
-    "bv": lambda d: (d["K"] * d["D"],),
-}
-GLOBAL_SHAPES = {
-    "embed": lambda d: (d["V"], d["d"]),
-    "final_norm": lambda d: (d["d"],),
-    "head": lambda d: (d["d"], d["V"]),
-}
-
-# where each plain name sits in the program's parameter tree
-# (repro.models.lm.TransformerLM with one scanned group, "blocks0")
-PROGRAM_PATHS = {
-    ("embed", "table"): "embed",
-    ("final_norm", "w"): "final_norm",
-    ("head", "w"): "head",
-    ("blocks0", "block", "norm1", "w"): "norm1",
-    ("blocks0", "block", "attn", "wq"): "wq",
-    ("blocks0", "block", "attn", "wk"): "wk",
-    ("blocks0", "block", "attn", "wv"): "wv",
-    ("blocks0", "block", "attn", "wo"): "wo",
-    ("blocks0", "block", "attn", "bq"): "bq",
-    ("blocks0", "block", "attn", "bk"): "bk",
-    ("blocks0", "block", "attn", "bv"): "bv",
-    ("blocks0", "block", "norm2", "w"): "norm2",
-    ("blocks0", "block", "ffn", "wg", "w"): "w_gate",
-    ("blocks0", "block", "ffn", "wi", "w"): "w_up",
-    ("blocks0", "block", "ffn", "wo", "w"): "w_down",
-}
-
-
-def dims(conf: dict) -> dict:
-    """Model dimensions from a configuration file's published keys."""
-    d = int(conf["hidden_size"])
-    H = int(conf["num_attention_heads"])
-    return {
-        "d": d, "H": H, "K": int(conf["num_key_value_heads"]),
-        "D": int(conf.get("head_dim") or d // H),
-        "F": int(conf["intermediate_size"]), "V": int(conf["vocab_size"]),
-        "L": int(conf["num_hidden_layers"]),
-        "bias": conf.get("model_type") == "qwen2"
-        or bool(conf.get("attention_bias", False)),
-        "tied": bool(conf.get("tie_word_embeddings", False)),
-        "theta": float(conf["rope_theta"]),
-        "eps": float(conf["rms_norm_eps"]),
-    }
-
-
-def layer_names(dm: dict) -> list[str]:
-    names = list(LAYER_SHAPES)
-    if dm["bias"]:
-        names += list(BIAS_SHAPES)
-    return names
-
-
-def _shape(name: str, dm: dict) -> tuple[int, ...]:
-    table = {**LAYER_SHAPES, **BIAS_SHAPES, **GLOBAL_SHAPES}
-    return table[name](dm)
-
-
-def _draw(key, name: str, shape: tuple[int, ...]):
-    """One tensor: uniform with the standard deviation its role asks for."""
-    u = jax.random.uniform(key, shape, jnp.float32, -1.0, 1.0) * np.sqrt(3.0)
-    if name in ("norm1", "norm2", "final_norm"):
-        return 1.0 + 0.1 * u  # fp32, as the program stores norms
-    if name == "embed":
-        std = 1.0
-    elif name in BIAS_SHAPES:
-        std = 0.5
-    else:
-        std = 1.0 / np.sqrt(shape[0])  # fan-in
-    return (u * std).astype(jnp.bfloat16)
-
-
-def _name_key(base, name: str):
-    return jax.random.fold_in(base, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-
-
-def _make(seed_words, *, dm_items):
-    dm = dict(dm_items)
-    base = jax.random.fold_in(jax.random.PRNGKey(seed_words[0]),
-                              seed_words[1])
-    names = layer_names(dm)
-    keys = {n: _name_key(base, n) for n in names}
-
-    def one_layer(layer):
-        return {n: _draw(jax.random.fold_in(keys[n], layer), n, _shape(n, dm))
-                for n in names}
-
-    out = jax.lax.map(one_layer, jnp.arange(dm["L"]))
-    for n in GLOBAL_SHAPES:
-        if n == "head" and dm["tied"]:
-            continue
-        out[n] = _draw(_name_key(base, n), n, _shape(n, dm))
-    return out
-
-
-@functools.lru_cache(maxsize=None)
-def _maker(dm_items, device):
-    sharding = jax.sharding.SingleDeviceSharding(device)
-    return jax.jit(functools.partial(_make, dm_items=dm_items),
-                   out_shardings=sharding)
+# standard deviation of each role's entries; "matrix" is 1/sqrt(fan-in),
+# "norm" is a float32 scale of 1 + 0.1 U (the program stores norms so)
+ROLE_STD = {"embed": 1.0, "bias": 0.5}
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -145,19 +37,61 @@ def seed_words(seed: int) -> np.ndarray:
                       np.uint32)
 
 
-def canonical(dm: dict, seed: int, device=None) -> dict:
-    """Every tensor of the model, per-layer ones stacked: {name: array}."""
+def base_key(words):
+    """The key of a whole set of weights, from `seed_words`."""
+    return jax.random.fold_in(jax.random.PRNGKey(words[0]), words[1])
+
+
+def name_key(base, name: str):
+    """The key of the tensor called `name`."""
+    return jax.random.fold_in(base, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+
+
+def draw(key, shape: tuple[int, ...], role: str):
+    """One tensor: uniform with the standard deviation its role asks for,
+    bfloat16 but for norms.  A matrix's fan-in is its second-last axis."""
+    u = jax.random.uniform(key, shape, jnp.float32, -1.0, 1.0) * np.sqrt(3.0)
+    if role == "norm":
+        return 1.0 + 0.1 * u
+    std = ROLE_STD.get(role) or 1.0 / np.sqrt(shape[-2])
+    return (u * std).astype(jnp.bfloat16)
+
+
+def draw_stack(base, table: dict, count: int) -> dict:
+    """`count` layers of the tensors in `table` ({name: (shape, role)}),
+    stacked on a leading axis; layer i of `name` is drawn from
+    `name_key(base, name)` folded with i."""
+    keys = {n: name_key(base, n) for n in table}
+
+    def one_layer(layer):
+        return {n: draw(jax.random.fold_in(keys[n], layer), shape, role)
+                for n, (shape, role) in table.items()}
+
+    return jax.lax.map(one_layer, jnp.arange(count))
+
+
+@functools.lru_cache(maxsize=None)
+def _maker(make, dm_items, device):
+    sharding = jax.sharding.SingleDeviceSharding(device)
+    return jax.jit(functools.partial(make, dm_items=dm_items),
+                   out_shardings=sharding)
+
+
+def canonical(make, dm: dict, seed: int, device=None) -> dict:
+    """`make(words, dm_items=...)` run on `device` as one jitted call for
+    the seed's words: every tensor of the model, {name: array}."""
     device = device or jax.devices()[0]
     words = jax.device_put(seed_words(seed), device)
-    return _maker(tuple(sorted(dm.items())), device)(words)
+    return _maker(make, tuple(sorted(dm.items())), device)(words)
 
 
-def program_params(canon: dict, abstract_tree: dict) -> dict:
+def place(canon: dict, abstract_tree: dict, paths: dict) -> dict:
     """`canon` laid out as the program's parameter tree, whose shapes and
-    dtypes `abstract_tree` gives; every leaf must map, shapes must agree."""
-    def place(path, leaf):
+    dtypes `abstract_tree` gives; `paths` maps each leaf's key path to a
+    plain name.  Every leaf must map, and shapes and dtypes must agree."""
+    def one(path, leaf):
         keys = tuple(getattr(p, "key", p) for p in path)
-        name = PROGRAM_PATHS.get(keys)
+        name = paths.get(keys)
         if name is None or name not in canon:
             raise KeyError(f"no benchmark tensor for program leaf {keys}")
         arr = canon[name]
@@ -166,4 +100,4 @@ def program_params(canon: dict, abstract_tree: dict) -> dict:
                              f"!= program {leaf.shape} {leaf.dtype}")
         return arr
 
-    return jax.tree_util.tree_map_with_path(place, abstract_tree)
+    return jax.tree_util.tree_map_with_path(one, abstract_tree)

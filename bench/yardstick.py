@@ -1,9 +1,7 @@
-"""Arithmetic of the metrics: percentiles, and the operations and bytes a
-step of the model needs, worked out from its shapes.
-
-The counts are of the work the model needs, not of what an implementation
-does: a matrix is read once per step, the key/value cache is read up to
-each sequence's live length and written for each new token.
+"""Arithmetic of the metrics that every family shares: percentiles, and the
+keys a span of tokens attends to.  What a step of a model needs in
+operations and bytes, worked out from its shapes, is its family's
+(`families/<name>.py`: `forward_flops`, `decode_bytes`).
 """
 
 from __future__ import annotations
@@ -21,46 +19,8 @@ def percentile(values, q: float) -> float:
     return float(vals[rank - 1])
 
 
-def matrix_params(dm: dict) -> int:
-    """Parameters that every token multiplies: the layers' matrices and
-    biases and the output head (the embedding is a row lookup)."""
-    d, H, K, D, F = dm["d"], dm["H"], dm["K"], dm["D"], dm["F"]
-    layer = d * H * D + 2 * d * K * D + H * D * d + 3 * d * F
-    if dm["bias"]:
-        layer += H * D + 2 * K * D
-    return dm["L"] * layer + d * dm["V"]
-
-
-def kv_bytes_per_token(dm: dict, itemsize: int = 2) -> int:
-    return dm["L"] * 2 * dm["K"] * dm["D"] * itemsize
-
-
-def weight_bytes(dm: dict) -> int:
-    """Bytes one step reads of the weights: bfloat16 matrices and head,
-    float32 norms."""
-    norms = (2 * dm["L"] + 1) * dm["d"] * 4
-    return 2 * matrix_params(dm) + norms
-
-
-def decode_bytes(dm: dict, kv_lens) -> int:
-    """Least bytes a decode step over sequences of live lengths `kv_lens`
-    (counting the new token) moves: weights once, embedding rows, the live
-    cache read and the new tokens' keys and values written."""
-    b = len(kv_lens)
-    per_tok = kv_bytes_per_token(dm)
-    return (weight_bytes(dm) + b * dm["d"] * 2
-            + sum(kv_lens) * per_tok + b * per_tok)
-
-
-def attention_flops(dm: dict, start: int, stop: int) -> int:
-    """Score and value products of the tokens at positions start..stop-1,
-    each attending causally to itself and everything before it."""
-    # sum over p in [start, stop) of (p + 1) keys
-    keys = (stop * (stop + 1) - start * (start + 1)) // 2
-    return 4 * dm["L"] * dm["H"] * dm["D"] * keys
-
-
-def forward_flops(dm: dict, start: int, stop: int) -> int:
-    """Model operations for computing tokens start..stop-1 of a sequence."""
-    return 2 * matrix_params(dm) * (stop - start) \
-        + attention_flops(dm, start, stop)
+def causal_keys(start: int, stop: int) -> int:
+    """Keys that the tokens at positions start..stop-1 attend to, each
+    causally to itself and everything before it: the sum over p in
+    [start, stop) of p + 1."""
+    return (stop * (stop + 1) - start * (start + 1)) // 2
